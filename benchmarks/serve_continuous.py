@@ -79,9 +79,10 @@ seeded trace replayed through engines on ``{data, model}`` meshes at
 every grid point of ``repro.launch.microbench.SHARDED_GRID``, tokens
 asserted bit-identical to the 1x1 replay, with ``sharded_tok_s`` /
 ``sharded_decode_step_ms`` / ``sharded_tokens_mismatch`` cells appended
-to ``--history`` for the regression gate.  It re-execs itself under
-``--xla_force_host_platform_device_count=8`` when fewer than 4 devices
-are visible, so the sweep runs on any CPU host.
+to ``--history`` for the regression gate.  On a CPU host with fewer
+than 4 devices it re-execs itself under
+``--xla_force_host_platform_device_count=8``; on an accelerator with
+fewer than 4 devices it fails instead of falling back to the CPU.
 """
 
 from __future__ import annotations
@@ -131,11 +132,13 @@ def decode_step_ms(model, cfg, *, batch, max_len, max_prompt_len,
     eng.step()  # admit every slot + compile the decode step
     key = eng._next_key()
     for _ in range(warmup):
-        eng.cache, eng.state, nxt, _ = eng._decode(eng.cache, eng.state, key)
+        eng.cache, eng.state, nxt, _ = eng._decode(eng._models, eng.cache,
+                                                   eng.state, key)
     jax.block_until_ready(nxt)
     t0 = time.perf_counter()
     for _ in range(iters):
-        eng.cache, eng.state, nxt, _ = eng._decode(eng.cache, eng.state, key)
+        eng.cache, eng.state, nxt, _ = eng._decode(eng._models, eng.cache,
+                                                   eng.state, key)
     jax.block_until_ready(nxt)
     return (time.perf_counter() - t0) / iters * 1e3
 
@@ -162,10 +165,10 @@ def spec_step_ms(model, draft, cfg, *, batch, max_prompt_len, block_size,
     for i in range(warmup + iters):
         t0 = time.perf_counter()
         eng.draft_cache, drafts = eng._spec_draft(
-            eng.draft_cache, eng.cache.length, eng.state)
+            eng._models, eng.draft_cache, eng.cache.length, eng.state)
         jax.block_until_ready(drafts)
         t1 = time.perf_counter()
-        out = eng._spec_verify(eng.cache, eng.state, drafts)
+        out = eng._spec_verify(eng._models, eng.cache, eng.state, drafts)
         eng.cache, eng.state = out[0], out[1]
         jax.block_until_ready(out[2])
         t2 = time.perf_counter()
@@ -792,8 +795,9 @@ def main(argv=None) -> int:
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--sharded", action="store_true",
                    help="run the dp x tp sharded serving sweep instead of "
-                        "the replay suite (re-execs itself under 8 forced "
-                        "CPU host devices when fewer than 4 are visible)")
+                        "the replay suite (on a CPU host with fewer than 4 "
+                        "devices, re-execs itself under 8 forced host "
+                        "devices)")
     p.add_argument("--priority", action="store_true",
                    help="run the priority + preemption scheduling cells "
                         "instead of the replay suite (step-count TTFT vs "
@@ -817,6 +821,9 @@ def main(argv=None) -> int:
         return 0
     if args.sharded:
         if len(jax.devices()) < 4:
+            if jax.default_backend() != "cpu":
+                p.error(f"--sharded needs 4 devices; {len(jax.devices())} "
+                        f"{jax.default_backend()} device(s) visible")
             env = dict(os.environ)
             env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
             env.setdefault("JAX_PLATFORMS", "cpu")

@@ -77,11 +77,21 @@ def _layer_key(base_key, path: str):
 
 
 def _rel_err(w, a, b) -> float:
-    """Relative Frobenius reconstruction error of W ≈ A @ B (stack-aware)."""
-    w32 = w.astype(jnp.float32)
-    diff = a.astype(jnp.float32) @ b.astype(jnp.float32) - w32
-    denom = jnp.maximum(jnp.linalg.norm(w32.reshape(-1)), 1e-30)
-    return float(jnp.linalg.norm(diff.reshape(-1)) / denom)
+    """Relative Frobenius reconstruction error of W ≈ A @ B (stack-aware;
+    one stacked matrix at a time, so the float32 residual is one
+    matrix's)."""
+    *_, m, n = w.shape
+    r = a.shape[-1]
+
+    def sq_norms(wab):
+        w32, a32, b32 = (x.astype(jnp.float32) for x in wab)
+        return jnp.sum((a32 @ b32 - w32) ** 2), jnp.sum(w32 ** 2)
+
+    num, den = jax.lax.map(sq_norms, (w.reshape(-1, m, n),
+                                      a.reshape(-1, m, r),
+                                      b.reshape(-1, r, n)))
+    return float(jnp.sqrt(num.sum())
+                 / jnp.maximum(jnp.sqrt(den.sum()), 1e-30))
 
 
 def _resolve_ungated(rank: Rank, m: int, n: int) -> int:

@@ -9,6 +9,8 @@ batched call) to a pair ``(A ∈ R^{..., m, r}, B ∈ R^{..., r, n})`` with
   only suitable for *factorization-by-design* (it does not approximate W).
 * ``svd``    — truncated SVD; the optimal rank-r approximation in Frobenius
   norm. The singular values are split symmetrically: ``A = U·√Σ, B = √Σ·Vᵀ``.
+  Stacked weights are solved one matrix at a time, so the float32
+  workspace is one matrix's, not the whole stack's.
 * ``snmf``   — semi-non-negative MF (Ding, Li & Jordan 2010): ``W ≈ A·B`` with
   ``B ≥ 0`` and ``A`` unconstrained, fitted by ``num_iter`` multiplicative
   updates.  Jittable (``lax.fori_loop``).
@@ -36,16 +38,35 @@ def random_solver(w: jax.Array, rank: int, *, key: jax.Array,
     return a, b
 
 
+def _thin_svd(w: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Thin SVD of one float32 matrix.  A wide or tall matrix is first
+    reduced by QR to its square triangular factor, so every matrix with the
+    same short side runs the same SVD program: on a TPU each SVD shape is
+    minutes of compile."""
+    m, n = w.shape
+    if m < n:
+        q, r = jnp.linalg.qr(w.T)  # w = rᵀ qᵀ
+        u, s, vt = jnp.linalg.svd(r.T)
+        return u, s, jnp.matmul(vt, q.T, precision="highest")
+    if m > n:
+        q, r = jnp.linalg.qr(w)  # w = q r
+        u, s, vt = jnp.linalg.svd(r)
+        return jnp.matmul(q, u, precision="highest"), s, vt
+    return jnp.linalg.svd(w)
+
+
 def svd_solver(w: jax.Array, rank: int, *, key: Optional[jax.Array] = None,
                num_iter: int = 0) -> tuple[jax.Array, jax.Array]:
     del key, num_iter
-    dtype = w.dtype
-    u, s, vt = jnp.linalg.svd(w.astype(jnp.float32), full_matrices=False)
-    u, s, vt = u[..., :rank], s[..., :rank], vt[..., :rank, :]
-    sq = jnp.sqrt(s)
-    a = u * sq[..., None, :]
-    b = sq[..., :, None] * vt
-    return a.astype(dtype), b.astype(dtype)
+    *stack, m, n = w.shape
+    a, b = [], []
+    for x in w.reshape(-1, m, n):  # one float32 workspace at a time
+        u, s, vt = _thin_svd(x.astype(jnp.float32))
+        sq = jnp.sqrt(s[:rank])
+        a.append((u[:, :rank] * sq).astype(w.dtype))
+        b.append((sq[:, None] * vt[:rank]).astype(w.dtype))
+    return (jnp.stack(a).reshape(*stack, m, rank),
+            jnp.stack(b).reshape(*stack, rank, n))
 
 
 def snmf_solver(w: jax.Array, rank: int, *, key: Optional[jax.Array] = None,

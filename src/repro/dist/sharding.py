@@ -129,7 +129,7 @@ def cache_specs(cache, mesh):
     Two layouts are distinguished by structure:
 
     * **paged** (a NamedTuple with a ``table`` field): the block pool k/v
-      are (layers, n_blocks, block_size, kv_heads, head_dim) — the pool is
+      are (layers, kv_heads, n_blocks, block_size, head_dim) — the pool is
       GLOBAL over data (every data shard holds the full pool; the host-side
       allocator hands out block ids with no notion of placement) and its
       kv-head dim shards over "model".  The block table (batch, max_table)
@@ -150,8 +150,8 @@ def cache_specs(cache, mesh):
     if fields is not None and "table" in fields:
         def pool(leaf) -> P:
             s: list = [None] * len(leaf.shape)
-            if len(leaf.shape) >= 2 and tp > 1 and leaf.shape[-2] % tp == 0:
-                s[-2] = "model"
+            if len(leaf.shape) >= 4 and tp > 1 and leaf.shape[-4] % tp == 0:
+                s[-4] = "model"
             return P(*s)
 
         def batch_dim(leaf, dim: int) -> P:

@@ -40,7 +40,10 @@ division rather than NaN.
 
 GQA/MQA fall out of the layout: ``q`` is reshaped to ``(kv_heads,
 W * group, head_dim)`` (row ``r = w * group + g``) and each grid step
-attends one KV head's query block.
+attends one KV head's query block.  The pool is head-major, ``(kv_heads,
+n_blocks, block_size, head_dim)``, so every fetched block is ``(block_size,
+head_dim)`` in its last two dims, as the TPU compiler's tiling requires
+(see :mod:`repro.kernels.paged_attention`).
 """
 
 from __future__ import annotations
@@ -52,7 +55,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.led_matmul import _CompilerParams
 from repro.kernels.ops import default_interpret
 from repro.kernels.ref import NEG_INF  # one mask fill value, kernel == oracle
 
@@ -97,8 +99,8 @@ def _chunk_attn_kernel(table_ref, meta_ref,  # scalar prefetch
     # at/past the offset hold nothing this chunk may attend — skip them.
     @pl.when((i < n_table) & (i * block_size < off))
     def _prefix():
-        k = kp_ref[0, :, 0].astype(jnp.float32)  # (block_size, head_dim)
-        v = vp_ref[0, :, 0].astype(jnp.float32)
+        k = kp_ref[0, 0].astype(jnp.float32)   # (block_size, head_dim)
+        v = vp_ref[0, 0].astype(jnp.float32)
         bid = table_ref[jnp.minimum(i, n_table - 1)]
         kpos = i * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_size), 1)
@@ -126,7 +128,7 @@ def _chunk_attn_kernel(table_ref, meta_ref,  # scalar prefetch
 def _chunk_attention_call(q3, k_pool, v_pool, k_chunk3, v_chunk3, table_row,
                           meta, *, interpret: bool):
     kvh, wg, hd = q3.shape
-    n_blocks, bs = k_pool.shape[:2]
+    n_blocks, bs = k_pool.shape[1:3]
     w = k_chunk3.shape[1]
     n_table = table_row.shape[0]
 
@@ -136,15 +138,15 @@ def _chunk_attention_call(q3, k_pool, v_pool, k_chunk3, v_chunk3, table_row,
         # The final grid step (the chunk half) never reads the pool refs —
         # clamp its index into range for the prefetch DMA.
         safe_i = jnp.minimum(i, n_table - 1)
-        return (jnp.minimum(table_ref[safe_i], n_blocks - 1), 0, kh, 0)
+        return (kh, jnp.minimum(table_ref[safe_i], n_blocks - 1), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(kvh, n_table + 1),
         in_specs=[
             pl.BlockSpec((1, wg, hd), lambda kh, i, t, m: (kh, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd), kv_map),
-            pl.BlockSpec((1, bs, 1, hd), kv_map),
+            pl.BlockSpec((1, 1, bs, hd), kv_map),
+            pl.BlockSpec((1, 1, bs, hd), kv_map),
             pl.BlockSpec((1, w, hd), lambda kh, i, t, m: (kh, 0, 0)),
             pl.BlockSpec((1, w, hd), lambda kh, i, t, m: (kh, 0, 0)),
         ],
@@ -161,7 +163,7 @@ def _chunk_attention_call(q3, k_pool, v_pool, k_chunk3, v_chunk3, table_row,
                           group=wg // w),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((kvh, wg, hd), q3.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(table_row, meta, q3, k_pool, v_pool, k_chunk3, v_chunk3)
@@ -169,8 +171,8 @@ def _chunk_attention_call(q3, k_pool, v_pool, k_chunk3, v_chunk3, table_row,
 
 def chunk_attention(
     q: jax.Array,          # (W, heads, head_dim) — one slot's chunk queries
-    k_pool: jax.Array,     # (n_blocks, block_size, kv_heads, head_dim)
-    v_pool: jax.Array,     # (n_blocks, block_size, kv_heads, head_dim)
+    k_pool: jax.Array,     # (kv_heads, n_blocks, block_size, head_dim)
+    v_pool: jax.Array,     # (kv_heads, n_blocks, block_size, head_dim)
     table_row: jax.Array,  # (max_table,) int32 — ONE slot's block table
     k_chunk: jax.Array,    # (W, kv_heads, head_dim) — the chunk's fresh K
     v_chunk: jax.Array,    # (W, kv_heads, head_dim)
@@ -192,7 +194,7 @@ def chunk_attention(
     if interpret is None:
         interpret = default_interpret()
     w, heads, hd = q.shape
-    n_blocks, bs, kvh, hd_k = k_pool.shape
+    kvh, n_blocks, bs, hd_k = k_pool.shape
     if hd_k != hd or v_pool.shape != k_pool.shape:
         raise ValueError(
             f"pool/query shape mismatch: q {q.shape}, k {k_pool.shape}, "
@@ -249,8 +251,8 @@ def chunk_attention_dense(
         k_lane = jnp.pad(k_lane, ((0, pad), (0, 0), (0, 0)))
         v_lane = jnp.pad(v_lane, ((0, pad), (0, 0), (0, 0)))
     n_table = (max_len + pad) // bs
-    k_pool = k_lane.reshape(n_table, bs, kvh, hd)
-    v_pool = v_lane.reshape(n_table, bs, kvh, hd)
+    k_pool = k_lane.reshape(n_table, bs, kvh, hd).transpose(2, 0, 1, 3)
+    v_pool = v_lane.reshape(n_table, bs, kvh, hd).transpose(2, 0, 1, 3)
     table_row = jnp.arange(n_table, dtype=jnp.int32)
     return chunk_attention(q, k_pool, v_pool, table_row, k_chunk, v_chunk,
                            offset, n_valid, interpret=interpret)

@@ -2,13 +2,11 @@
 
 ``led_matmul`` accepts arbitrary leading batch axes, pads every matmul dim up
 to the block grid, dispatches to the fused kernel, and slices the result
-back.  On non-TPU backends (this container is CPU-only) it runs the kernel in
-``interpret=True`` mode so tests exercise the *same* kernel body everywhere.
+back.  On non-TPU backends it runs the kernel in ``interpret=True`` mode so
+tests exercise the *same* kernel body everywhere.
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -18,15 +16,10 @@ from repro.kernels.ref import led_matmul_ref
 
 
 def default_interpret() -> bool:
-    """Shared interpret-mode policy for every Pallas kernel in the repo.
-
-    Off-TPU backends (this container is CPU-only) run the *same* kernel
-    bodies in ``interpret=True`` mode so tests exercise them everywhere;
-    ``REPRO_PALLAS_INTERPRET=1`` forces interpret mode even on TPU (the
-    CI ``kernels-interpret`` job sets it so kernel regressions are caught
-    without hardware)."""
-    if os.environ.get("REPRO_PALLAS_INTERPRET", "0") not in ("", "0"):
-        return True
+    """Shared interpret-mode policy for every Pallas kernel in the repo,
+    chosen by platform alone: compiled on TPU, interpreted (the *same*
+    kernel bodies, so tests exercise them everywhere) on any other
+    backend."""
     return jax.default_backend() != "tpu"
 
 

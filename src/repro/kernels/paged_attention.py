@@ -1,11 +1,12 @@
 """Fused Pallas paged-attention decode kernel.
 
 Single-query (decode-step) attention straight against the paged KV layout
-of :class:`repro.nn.attention.PagedKVCache`: the shared block pool, the
-per-slot block tables, and the per-slot positions.  The dense-gather
-baseline first materializes a ``(batch, max_len, kv_heads, head_dim)``
-view of every slot's cache (``pool[table[b, p // bs] * bs + p % bs]``)
-and then runs masked attention over it — a full HBM round-trip of the
+of :class:`repro.nn.attention.PagedKVCache`: the shared head-major block
+pool ``(kv_heads, n_blocks, block_size, head_dim)``, the per-slot block
+tables, and the per-slot positions.  The dense-gather baseline first
+materializes a ``(batch, max_len, kv_heads, head_dim)`` view of every
+slot's cache (``pool[:, table[b, p // bs], p % bs]``) and then runs
+masked attention over it — a full HBM round-trip of the
 whole gathered cache per decode step.  This kernel fuses the gather into
 a flash-style online-softmax loop: KV blocks stream from the pool into
 VMEM one at a time (the block table is a scalar-prefetch operand, so each
@@ -17,8 +18,9 @@ all sequential ("arbitrary") so the per-(b, kh) running max / sum /
 accumulator scratch persists across the ``i`` steps:
 
   * ``i == 0``: zero the online-softmax carry.
-  * every ``i``: fetch pool block ``table[b, i]`` (clamped to a real row
-    — the unmapped sentinel ``n_blocks`` is masked in-kernel instead),
+  * every ``i``: fetch pool block ``table[b, i]`` of KV head ``kh``
+    (clamped to a real row — the unmapped sentinel ``n_blocks`` is masked
+    in-kernel instead),
     accumulate ``softmax(q k^T / sqrt(d)) v`` for the ``group =
     heads // kv_heads`` query heads that share KV head ``kh``.
   * ``i == last``: emit the normalized output block.
@@ -32,6 +34,11 @@ than NaN.
 
 GQA/MQA fall out of the layout: ``q`` is reshaped to ``(batch, kv_heads,
 group, head_dim)`` and each grid step attends one KV head's query group.
+
+The pool is head-major so that each fetched block is ``(block_size,
+head_dim)`` in the last two dims: the TPU compiler requires a block's last
+two dims to be multiples of the ``(8, 128)`` tile (or the whole array
+dims), which a one-head slice in the second-minor place never is.
 """
 
 from __future__ import annotations
@@ -43,7 +50,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.led_matmul import _CompilerParams
 from repro.kernels.ops import default_interpret
 from repro.kernels.ref import NEG_INF  # one mask fill value, kernel == oracle
 
@@ -62,8 +68,8 @@ def _paged_attn_kernel(table_ref, pos_ref,  # scalar prefetch
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)        # (group, head_dim)
-    k = k_ref[0, :, 0].astype(jnp.float32)     # (block_size, head_dim)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)        # (block_size, head_dim)
+    v = v_ref[0, 0].astype(jnp.float32)
     logits = jax.lax.dot_general(               # (group, block_size)
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) / jnp.sqrt(
@@ -98,13 +104,13 @@ def _paged_attn_kernel(table_ref, pos_ref,  # scalar prefetch
 def _paged_attention_call(q4, k_pool, v_pool, table, pos, *,
                           interpret: bool):
     batch, kvh, group, hd = q4.shape
-    n_blocks, bs = k_pool.shape[:2]
+    n_blocks, bs = k_pool.shape[1:3]
     n_table = table.shape[1]
 
     def kv_map(b, kh, i, table_ref, pos_ref):
         # sentinel entries (n_blocks, one past the pool) are clamped to a
         # real block for the fetch; the kernel masks their lanes to zero
-        return (jnp.minimum(table_ref[b, i], n_blocks - 1), 0, kh, 0)
+        return (kh, jnp.minimum(table_ref[b, i], n_blocks - 1), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -112,8 +118,8 @@ def _paged_attention_call(q4, k_pool, v_pool, table, pos, *,
         in_specs=[
             pl.BlockSpec((1, 1, group, hd),
                          lambda b, kh, i, t, p: (b, kh, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd), kv_map),
-            pl.BlockSpec((1, bs, 1, hd), kv_map),
+            pl.BlockSpec((1, 1, bs, hd), kv_map),
+            pl.BlockSpec((1, 1, bs, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, group, hd),
                                lambda b, kh, i, t, p: (b, kh, 0, 0)),
@@ -128,7 +134,7 @@ def _paged_attention_call(q4, k_pool, v_pool, table, pos, *,
                           n_blocks=n_blocks, n_table=n_table),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, kvh, group, hd), q4.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(table, pos, q4, k_pool, v_pool)
@@ -136,8 +142,8 @@ def _paged_attention_call(q4, k_pool, v_pool, table, pos, *,
 
 def paged_attention(
     q: jax.Array,       # (batch, heads, head_dim) — the one decode query
-    k_pool: jax.Array,  # (n_blocks, block_size, kv_heads, head_dim)
-    v_pool: jax.Array,  # (n_blocks, block_size, kv_heads, head_dim)
+    k_pool: jax.Array,  # (kv_heads, n_blocks, block_size, head_dim)
+    v_pool: jax.Array,  # (kv_heads, n_blocks, block_size, head_dim)
     table: jax.Array,   # (batch, max_table) int32; sentinel == n_blocks
     pos: jax.Array,     # (batch,) int32 — query position; attends kpos <= pos
     *,
@@ -154,7 +160,7 @@ def paged_attention(
     if interpret is None:
         interpret = default_interpret()
     batch, heads, hd = q.shape
-    n_blocks, bs, kvh, hd_k = k_pool.shape
+    kvh, n_blocks, bs, hd_k = k_pool.shape
     if hd_k != hd or v_pool.shape != k_pool.shape:
         raise ValueError(
             f"pool/query shape mismatch: q {q.shape}, k {k_pool.shape}, "

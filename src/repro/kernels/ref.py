@@ -35,28 +35,28 @@ def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     guarded division so a fully-masked slot yields zeros (``jax.nn.
     softmax`` would yield uniform weights there instead).
 
-    q: (batch, heads, head_dim); k/v_pool: (n_blocks, block_size,
-    kv_heads, head_dim); table: (batch, max_table) int32 with sentinel
+    q: (batch, heads, head_dim); k/v_pool: (kv_heads, n_blocks,
+    block_size, head_dim); table: (batch, max_table) int32 with sentinel
     ``n_blocks``; pos: (batch,) int32 -> (batch, heads, head_dim).
     """
     batch, heads, hd = q.shape
-    n_blocks, bs, kvh, _ = k_pool.shape
+    kvh, n_blocks, bs, _ = k_pool.shape
     group = heads // kvh
     n_table = table.shape[1]
     kpos = jnp.arange(n_table * bs)
     safe = jnp.minimum(table, n_blocks - 1)  # clamp sentinel for the gather
-    rows = safe[:, kpos // bs] * bs + (kpos % bs)[None, :]
-    gk = k_pool.reshape(n_blocks * bs, kvh, hd)[rows].astype(jnp.float32)
-    gv = v_pool.reshape(n_blocks * bs, kvh, hd)[rows].astype(jnp.float32)
+    blk, off = safe[:, kpos // bs], (kpos % bs)[None, :]
+    gk = k_pool[:, blk, off].astype(jnp.float32)  # (kvh, batch, S, hd)
+    gv = v_pool[:, blk, off].astype(jnp.float32)
     valid = ((kpos[None, :] <= pos[:, None])
              & (table[:, kpos // bs] != n_blocks))  # (batch, S)
     qf = q.astype(jnp.float32).reshape(batch, kvh, group, hd)
-    logits = jnp.einsum("bkgd,bskd->bkgs", qf, gk) / jnp.sqrt(
+    logits = jnp.einsum("bkgd,kbsd->bkgs", qf, gk) / jnp.sqrt(
         jnp.float32(hd))
     logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
     m = logits.max(-1, keepdims=True)
     p = jnp.where(valid[:, None, None, :], jnp.exp(logits - m), 0.0)
-    out = jnp.einsum("bkgs,bskd->bkgd", p, gv) / jnp.maximum(
+    out = jnp.einsum("bkgs,kbsd->bkgd", p, gv) / jnp.maximum(
         p.sum(-1, keepdims=True), 1e-30)
     return out.reshape(batch, heads, hd).astype(q.dtype)
 
@@ -76,20 +76,20 @@ def chunk_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     ``kpos <= qpos`` masking of the dense ``prefill_chunk`` gather for
     every valid row).  fp32 accumulation, guarded division.
 
-    q: (W, heads, head_dim); k/v_pool: (n_blocks, block_size, kv_heads,
+    q: (W, heads, head_dim); k/v_pool: (kv_heads, n_blocks, block_size,
     head_dim); table_row: (max_table,) int32 with sentinel ``n_blocks``;
     k/v_chunk: (W, kv_heads, head_dim); offset/n_valid: () int32
     -> (W, heads, head_dim).
     """
     w, heads, hd = q.shape
-    n_blocks, bs, kvh, _ = k_pool.shape
+    kvh, n_blocks, bs, _ = k_pool.shape
     group = heads // kvh
     n_table = table_row.shape[0]
     kpos = jnp.arange(n_table * bs)
     safe = jnp.minimum(table_row, n_blocks - 1)  # clamp sentinel for gather
-    rows = safe[kpos // bs] * bs + kpos % bs
-    gk = k_pool.reshape(n_blocks * bs, kvh, hd)[rows].astype(jnp.float32)
-    gv = v_pool.reshape(n_blocks * bs, kvh, hd)[rows].astype(jnp.float32)
+    blk, off = safe[kpos // bs], kpos % bs
+    gk = k_pool[:, blk, off].transpose(1, 0, 2).astype(jnp.float32)
+    gv = v_pool[:, blk, off].transpose(1, 0, 2).astype(jnp.float32)
     prefix_valid = (kpos < offset) & (table_row[kpos // bs] != n_blocks)
     j = jnp.arange(w)
     chunk_valid = (j[None, :] <= j[:, None]) & (j[None, :] < n_valid)

@@ -34,6 +34,20 @@ _OP_RE = re.compile(
     + "|".join(_COLLECTIVES) + r")(?:-start|-done)?\(")
 
 
+# e.g. ... custom_call_target="tpu_custom_call" ... metadata={op_name=
+# "jit(step)/while/body/jit(_paged_attention_call)/pallas_call" ...}
+_PALLAS_RE = re.compile(r"\(([\w.]+)\)/pallas_call")
+
+
+def pallas_kernels(hlo_text: str) -> set:
+    """Names of the jitted wrappers whose ``pallas_call`` became a TPU
+    kernel (``tpu_custom_call``) in a compiled program's text.  Empty for a
+    program whose kernels ran in interpret mode."""
+    return {name for line in hlo_text.splitlines()
+            if "tpu_custom_call" in line
+            for name in _PALLAS_RE.findall(line)}
+
+
 def _shape_bytes(dtype: str, dims: str) -> int:
     size = _DTYPE_BYTES.get(dtype, 4)
     for d in dims.split(","):

@@ -32,9 +32,10 @@ Four metric families, swept over (batch, seq, block_size, heads):
 ``--sharded`` swaps the kernel matrix for the dp x tp serve grid
 (:data:`SHARDED_GRID`): the reference decode / prefill-chunk steps with
 params, paged pool, and activations placed on a ``{data, model}`` mesh
-(variants ``sharded_dp{dp}tp{tp}``).  It re-execs itself under
-``--xla_force_host_platform_device_count=8`` when fewer than 4 devices
-are visible, so the grid runs anywhere.
+(variants ``sharded_dp{dp}tp{tp}``).  On a CPU host with fewer than 4
+devices it re-execs itself under
+``--xla_force_host_platform_device_count=8``; on an accelerator with
+fewer than 4 devices it fails instead of falling back to the CPU.
 
 Timing methodology: the first call (trace + compile + first run) is
 recorded as ``compile_ms``, never mixed into steady state; ``warmup``
@@ -72,8 +73,8 @@ def provenance() -> dict:
     """The stamp every emitted cell carries.
 
     ``interpret_mode`` is the repo-wide Pallas policy
-    (:func:`repro.kernels.ops.default_interpret`): True off-TPU or under
-    ``REPRO_PALLAS_INTERPRET=1``.  ``compiled_backend`` is the backend a
+    (:func:`repro.kernels.ops.default_interpret`): True off-TPU, chosen
+    by platform alone.  ``compiled_backend`` is the backend a
     kernel timing actually compiled for — ``None`` in interpret mode,
     because an interpreted timing measures the Pallas interpreter, not
     any hardware.  Two cells are comparable only when both fields (and
@@ -203,9 +204,9 @@ def _synthetic_paged(rng, *, batch, seq, block_size, heads, kvh, head_dim,
     n_table = -(-seq // block_size)
     n_blocks = batch * n_table + slack_blocks
     kp = jnp.asarray(rng.standard_normal(
-        (n_blocks, block_size, kvh, head_dim)), jnp.float32)
+        (kvh, n_blocks, block_size, head_dim)), jnp.float32)
     vp = jnp.asarray(rng.standard_normal(
-        (n_blocks, block_size, kvh, head_dim)), jnp.float32)
+        (kvh, n_blocks, block_size, head_dim)), jnp.float32)
     table = np.full((batch, n_table), n_blocks, np.int32)
     perm = rng.permutation(batch * n_table)
     table[:, :] = perm.reshape(batch, n_table)
@@ -222,9 +223,9 @@ def _synthetic_chunk(rng, *, seq, block_size, width, heads, kvh, head_dim):
     n_table = -(-seq // block_size)
     n_blocks = n_table + 2
     kp = jnp.asarray(rng.standard_normal(
-        (n_blocks, block_size, kvh, head_dim)), jnp.float32)
+        (kvh, n_blocks, block_size, head_dim)), jnp.float32)
     vp = jnp.asarray(rng.standard_normal(
-        (n_blocks, block_size, kvh, head_dim)), jnp.float32)
+        (kvh, n_blocks, block_size, head_dim)), jnp.float32)
     table = jnp.asarray(rng.permutation(n_blocks)[:n_table], jnp.int32)
     q = jnp.asarray(rng.standard_normal((width, heads, head_dim)),
                     jnp.float32)
@@ -553,11 +554,15 @@ def main(argv=None) -> int:
                         "one trace per cell under this directory")
     p.add_argument("--sharded", action="store_true",
                    help="run the dp x tp sharded step sweep instead of the "
-                        "kernel matrix (re-execs itself under 8 forced CPU "
-                        "host devices when fewer than 4 are visible)")
+                        "kernel matrix (on a CPU host with fewer than 4 "
+                        "devices, re-execs itself under 8 forced host "
+                        "devices)")
     args = p.parse_args(argv)
     iters = args.iters or (10 if args.smoke else 30)
     if args.sharded and len(jax.devices()) < 4:
+        if jax.default_backend() != "cpu":
+            p.error(f"--sharded needs 4 devices; {len(jax.devices())} "
+                    f"{jax.default_backend()} device(s) visible")
         env = dict(os.environ)
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         env.setdefault("JAX_PLATFORMS", "cpu")

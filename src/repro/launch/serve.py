@@ -106,6 +106,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import auto_fact
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.serve import (ContinuousEngine, bench_trace, format_kv_stats,
                          format_prefill_stats, format_stats,
@@ -266,6 +267,7 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
     if args.fact_rank:  # pre-PR6 spelling
         args.factorize, args.rank = True, args.fact_rank
+    use_compile_cache()
 
     min_prompt = 4
     if not 0 <= args.shared_prefix <= args.max_prompt_len - min_prompt:

@@ -29,6 +29,7 @@ from repro.configs import get_config
 from repro.core import auto_fact
 from repro.core.gradcomp import init_compressor
 from repro.data import markov_lm_batch
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.optim import AdamW, linear_warmup_cosine
 from repro.train import TrainState, make_train_step
@@ -53,6 +54,7 @@ def main(argv=None) -> int:
     p.add_argument("--reduced", action="store_true",
                    help="use the reduced (smoke) config of the arch")
     args = p.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced or args.arch != "paper-tiny":

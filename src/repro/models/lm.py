@@ -203,8 +203,9 @@ class TransformerLM(Module):
                          dtype=jnp.bfloat16) -> PagedKVCache:
         """Shared KV block pool + per-slot block tables.
 
-        Pool k/v carry a leading layer dim (``(n_layers, n_blocks,
-        block_size, kvh, hd)``) and per-layer lengths scan with the blocks;
+        Pool k/v carry a leading layer dim (``(n_layers, kvh, n_blocks,
+        block_size, hd)``, head-major per layer — see :class:`PagedKVCache`)
+        and per-layer lengths scan with the blocks;
         the block table is layer-invariant (every layer mirrors the same
         allocation) so it is stored once and closed over by the decode
         scan.  Unmapped table entries hold the sentinel ``n_blocks``."""
@@ -217,9 +218,9 @@ class TransformerLM(Module):
         kvh, hd = cfg.n_kv_heads, cfg.resolved_head_dim
         max_table = -(-max_len // block_size)
         return PagedKVCache(
-            k=jnp.zeros((self.n_layers, n_blocks, block_size, kvh, hd),
+            k=jnp.zeros((self.n_layers, kvh, n_blocks, block_size, hd),
                         dtype),
-            v=jnp.zeros((self.n_layers, n_blocks, block_size, kvh, hd),
+            v=jnp.zeros((self.n_layers, kvh, n_blocks, block_size, hd),
                         dtype),
             table=jnp.full((batch, max_table), n_blocks, jnp.int32),
             length=jnp.zeros((self.n_layers, batch), jnp.int32),

@@ -69,17 +69,23 @@ class PagedKVCache(NamedTuple):
 
     Instead of each slot reserving a dense ``max_len`` lane, all slots
     share one pool of fixed-size blocks; a per-slot block table maps
-    logical position ``p`` to pool row ``table[slot, p // bs] * bs +
-    p % bs``.  HBM spent on KV is proportional to live tokens, not to
-    ``batch * max_len``.  Block ownership, refcounts, and prefix sharing
-    live host-side in :mod:`repro.serve.paging`; table entries equal to
-    ``n_blocks`` (one past the last block) are the unmapped sentinel —
-    scatters there drop, gathers clip into lanes the position mask
-    already excludes.
+    logical position ``p`` to block ``table[slot, p // bs]``, row
+    ``p % bs`` (flat pool row ``block * bs + p % bs``, the form the host
+    side hands over).  HBM spent on KV is proportional to live tokens,
+    not to ``batch * max_len``.  Block ownership, refcounts, and prefix
+    sharing live host-side in :mod:`repro.serve.paging`; table entries
+    equal to ``n_blocks`` (one past the last block) are the unmapped
+    sentinel — scatters there drop, gathers clip into lanes the position
+    mask already excludes.
+
+    The pool is head-major so that one KV head's block is a contiguous
+    ``(block_size, head_dim)`` tile: the Pallas kernels fetch exactly
+    that, and the TPU compiler refuses a block whose last two dims are
+    not whole ``(8, 128)`` tiles.
     """
 
-    k: jax.Array  # (n_blocks, block_size, kv_heads, head_dim)
-    v: jax.Array  # (n_blocks, block_size, kv_heads, head_dim)
+    k: jax.Array  # (kv_heads, n_blocks, block_size, head_dim)
+    v: jax.Array  # (kv_heads, n_blocks, block_size, head_dim)
     table: jax.Array  # (batch, max_blocks_per_seq) int32 pool block ids
     length: jax.Array  # (batch,) int32 — valid positions per slot
 
@@ -340,33 +346,33 @@ class Attention(Module):
                 raise NotImplementedError(
                     "paged chunked prefill supports global attention only; "
                     "sliding-window layers use the ring-buffer KVCache path")
-            nb, bs, kvh, hd = cache.k.shape
+            bs = cache.k.shape[2]
             max_table = cache.table.shape[1]
-            pool_k = cache.k.reshape(nb * bs, kvh, hd)
-            pool_v = cache.v.reshape(nb * bs, kvh, hd)
-            pool_k = pool_k.at[dst].set(k[0].astype(pool_k.dtype),
-                                        mode="drop")
-            pool_v = pool_v.at[dst].set(v[0].astype(pool_v.dtype),
-                                        mode="drop")
+            # flat rows -> (block, row); the sentinel row n_blocks * bs
+            # lands on block n_blocks, out of range, and drops
+            blk, off = dst // bs, dst % bs
+            pool_k = cache.k.at[:, blk, off].set(
+                k[0].swapaxes(0, 1).astype(cache.k.dtype), mode="drop")
+            pool_v = cache.v.at[:, blk, off].set(
+                v[0].swapaxes(0, 1).astype(cache.v.dtype), mode="drop")
             if prefill_kernel == "pallas":
                 from repro.kernels.chunk_attention import chunk_attention
 
                 out = chunk_attention(
-                    q[0], pool_k.reshape(cache.k.shape),
-                    pool_v.reshape(cache.v.shape), cache.table[slot],
+                    q[0], pool_k, pool_v, cache.table[slot],
                     k[0].astype(pool_k.dtype), v[0].astype(pool_v.dtype),
                     offset, n_valid).reshape(1, w, -1)
             else:
                 kpos = jnp.arange(max_table * bs)
-                rows = cache.table[slot, kpos // bs] * bs + kpos % bs
-                gk = pool_k[rows][None].astype(x.dtype)  # (1, S, kvh, hd)
-                gv = pool_v[rows][None].astype(x.dtype)
+                blk_s, off_s = cache.table[slot, kpos // bs], kpos % bs
+                # (kvh, S, hd) -> (1, S, kvh, hd)
+                gk = pool_k[:, blk_s, off_s].swapaxes(0, 1)[None]
+                gv = pool_v[:, blk_s, off_s].swapaxes(0, 1)[None]
                 valid = kpos[None, :] <= qpos[:, None]  # (W, S)
-                out = self._attend(q, gk, gv, valid[None, None])
+                out = self._attend(q, gk.astype(x.dtype), gv.astype(x.dtype),
+                                   valid[None, None])
             length = cache.length.at[slot].set(offset + n_valid)
-            new_cache = PagedKVCache(pool_k.reshape(cache.k.shape),
-                                     pool_v.reshape(cache.v.shape),
-                                     cache.table, length)
+            new_cache = PagedKVCache(pool_k, pool_v, cache.table, length)
         elif self._is_ring(cache):
             if prefill_kernel == "pallas":
                 raise NotImplementedError(
@@ -530,8 +536,8 @@ class Attention(Module):
                       ) -> tuple[jax.Array, PagedKVCache]:
         """Decode ``s`` tokens per slot against the shared block pool.
 
-        Each new K/V row is scattered to ``table[b, p // bs] * bs +
-        p % bs`` for ``p = pos .. pos + s - 1`` (``mode='drop'``: slots
+        Each new K/V row is scattered to block ``table[b, p // bs]``, row
+        ``p % bs`` for ``p = pos .. pos + s - 1`` (``mode='drop'``: slots
         whose table entry is the unmapped sentinel — finished, never
         admitted, or positions past the slot's block reservation — write
         nowhere, so a frozen slot can never clobber a block recycled to
@@ -559,22 +565,21 @@ class Attention(Module):
         qpos = pos[:, None] + jnp.arange(s)[None, :]  # (b, s)
         positions = qpos.astype(jnp.int32)
         q, k, v = self._qkv(x, positions=positions, kv_positions=positions)
-        nb, bs, kvh, hd = cache.k.shape
+        nb, bs = cache.k.shape[1:3]
         max_table = cache.table.shape[1]
-        pool_k = cache.k.reshape(nb * bs, kvh, hd)
-        pool_v = cache.v.reshape(nb * bs, kvh, hd)
         # a slot frozen at pos == max_table*bs (cache_full eviction) would
         # index one past the table; clamp the lookup and route its write to
-        # the sentinel row explicitly — take_along_axis's out-of-bounds fill
-        # (INT32_MIN) times bs wraps around int32 to a VALID row otherwise
+        # the sentinel block explicitly — take_along_axis's out-of-bounds
+        # fill (INT32_MIN) would otherwise index from the end of the pool
         blk = jnp.take_along_axis(
             cache.table, jnp.minimum(qpos // bs, max_table - 1), axis=1)
-        row_new = jnp.where(qpos < max_table * bs, blk * bs + qpos % bs,
-                            nb * bs)  # (b, s) flat pool rows for these tokens
-        pool_k = pool_k.at[row_new].set(k.astype(pool_k.dtype), mode="drop")
-        pool_v = pool_v.at[row_new].set(v.astype(pool_v.dtype), mode="drop")
-        new_k = pool_k.reshape(nb, bs, kvh, hd)
-        new_v = pool_v.reshape(nb, bs, kvh, hd)
+        blk = jnp.where(qpos < max_table * bs, blk, nb)  # (b, s)
+        # (b, s, kvh, hd) -> (kvh, b, s, hd), the order pool[:, blk, off]
+        # indexes in
+        new_k = cache.k.at[:, blk, qpos % bs].set(
+            jnp.moveaxis(k, 2, 0).astype(cache.k.dtype), mode="drop")
+        new_v = cache.v.at[:, blk, qpos % bs].set(
+            jnp.moveaxis(v, 2, 0).astype(cache.v.dtype), mode="drop")
         if kernel == "pallas" and s == 1:
             from repro.kernels.paged_attention import paged_attention
 
@@ -582,9 +587,10 @@ class Attention(Module):
             out = out.reshape(b, 1, self.num_heads * self.head_dim)
         else:
             kpos = jnp.arange(max_table * bs)
-            rows = cache.table[:, kpos // bs] * bs + (kpos % bs)[None, :]
-            gk = pool_k[rows].astype(x.dtype)  # (b, max_table*bs, kvh, hd)
-            gv = pool_v[rows].astype(x.dtype)
+            blk_s, off_s = cache.table[:, kpos // bs], (kpos % bs)[None, :]
+            # (kvh, b, S, hd) -> (b, S, kvh, hd)
+            gk = jnp.moveaxis(new_k[:, blk_s, off_s], 0, 2).astype(x.dtype)
+            gv = jnp.moveaxis(new_v[:, blk_s, off_s], 0, 2).astype(x.dtype)
             valid = kpos[None, None, :] <= qpos[:, :, None]  # (b, s, S)
             out = self._attend(q, gk, gv, valid[:, None])
         return (constrain_acts(self.o_proj(out)),

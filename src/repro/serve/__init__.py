@@ -19,9 +19,9 @@ Five modules:
   step — a long prompt never freezes the running decode lanes.  The
   default KV layout is **paged**: all slots share a pool of
   ``block_size``-token KV blocks (``PagedKVCache.k/v: (n_layers,
-  n_blocks, block_size, kv_heads, head_dim)``) and each slot maps
-  logical position ``p`` to pool row ``table[slot, p // block_size] *
-  block_size + p % block_size`` through its block-table row (``table:
+  kv_heads, n_blocks, block_size, head_dim)``) and each slot maps
+  logical position ``p`` to block ``table[slot, p // block_size]``, row
+  ``p % block_size`` through its block-table row (``table:
   (batch, ceil(max_len / block_size))`` int32, sentinel ``n_blocks`` for
   unmapped entries); HBM spent on KV is proportional to live tokens, not
   ``batch * max_len``.  A prompt whose prefix is already resident starts

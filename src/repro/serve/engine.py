@@ -514,9 +514,14 @@ class ContinuousEngine:
         pk_kw = ({} if prefill_kernel == "reference"
                  else {"prefill_kernel": prefill_kernel})
 
+        # Every jitted role takes the weights as its first argument,
+        # ``models = (model, draft_model)``: weights a jitted function
+        # closes over are baked into its compiled program as constants —
+        # one copy of the whole model per program on the device.
         if draft_model is None:
-            def chunk_fn(need_logits, toks, cache, slot, offset, n_valid,
-                         dst=None):
+            def chunk_fn(need_logits, models, toks, cache, slot, offset,
+                         n_valid, dst=None):
+                model = models[0]
                 kw = {} if dst is None else {"dst": dst}
                 return model.prefill_chunk(toks, cache, slot=slot,
                                            offset=offset, n_valid=n_valid,
@@ -526,8 +531,9 @@ class ContinuousEngine:
             # the draft prefills the same chunk into its own cache (logits
             # never needed — the verifier's final chunk seeds the first
             # sample; the draft only ever decodes)
-            def chunk_fn(need_logits, toks, cache, dcache, slot, offset,
-                         n_valid, dst=None):
+            def chunk_fn(need_logits, models, toks, cache, dcache, slot,
+                         offset, n_valid, dst=None):
+                model, draft_model = models
                 kw = {} if dst is None else {"dst": dst}
                 logits, cache = model.prefill_chunk(
                     toks, cache, slot=slot, offset=offset, n_valid=n_valid,
@@ -557,20 +563,17 @@ class ContinuousEngine:
             # families keep their original decode signature
             dk = self.decode_kernel
 
-            def model_decode(tok, cache):
-                return model.decode(tok, cache, decode_kernel=dk)
-
-            def draft_decode(tok, dcache):
-                return draft_model.decode(tok, dcache, decode_kernel=dk)
+            def model_decode(m, tok, cache):
+                return m.decode(tok, cache, decode_kernel=dk)
         else:
-            model_decode = model.decode
-            draft_decode = (draft_model.decode if draft_model is not None
-                            else None)
+            def model_decode(m, tok, cache):
+                return m.decode(tok, cache)
 
         stateful = self.cache_kind != "kv"
 
-        def decode_fn(cache, state, key):
-            logits, new_cache = model_decode(state.tok[:, None], cache)
+        def decode_fn(models, cache, state, key):
+            logits, new_cache = model_decode(models[0], state.tok[:, None],
+                                             cache)
             nxt = sample_tokens(logits[:, 0], state.temp, key)
             nxt = jnp.where(state.active, nxt, state.tok)
             if stateful:
@@ -597,7 +600,7 @@ class ContinuousEngine:
                                    n_gen=n_gen)
             return new_cache, state, nxt, done
 
-        def spec_draft_fn(dcache, vlen, state):
+        def spec_draft_fn(models, dcache, vlen, state):
             """Draft ``spec_k`` greedy tokens per slot with the factorized
             model (cheap single-token steps).  The draft frontier is synced
             from the VERIFIER's length ``vlen`` at entry — the verifier's
@@ -610,7 +613,7 @@ class ContinuousEngine:
 
             def body(carry, _):
                 tok, dc = carry
-                logits, dc = draft_decode(tok[:, None], dc)
+                logits, dc = model_decode(models[1], tok[:, None], dc)
                 nxt = greedy_tokens(logits[:, 0])
                 nxt = jnp.where(state.active, nxt, tok)
                 return (nxt, dc), nxt
@@ -619,7 +622,7 @@ class ContinuousEngine:
                 body, (state.tok, dcache), None, length=spec_k)
             return dcache, drafts.T  # (B, k)
 
-        def spec_verify_fn(cache, state, drafts):
+        def spec_verify_fn(models, cache, state, drafts):
             """Verify ``spec_k`` drafted tokens in ONE dense multi-token
             decode and commit the agreeing prefix + one correction token.
 
@@ -636,7 +639,7 @@ class ContinuousEngine:
             pos0 = cache.length[0]  # (B,) pre-decode frontier, all layers ==
             inp = jnp.concatenate([state.tok[:, None], drafts[:, :-1]],
                                   axis=1)
-            logits, cache = model_decode(inp, cache)
+            logits, cache = model_decode(models[0], inp, cache)
             g = greedy_tokens(logits)  # (B, k)
             lead = jnp.cumprod((drafts == g).astype(jnp.int32), axis=1)
             n_match = lead.sum(axis=1)  # leading drafts that verified
@@ -688,16 +691,18 @@ class ContinuousEngine:
 
             inner_chunk = chunk_fn
             if draft_model is None:
-                def chunk_fn(need_logits, toks, cache, *rest):
+                def chunk_fn(need_logits, models, toks, cache, *rest):
                     with activation_mesh(mesh):
-                        logits, c = inner_chunk(need_logits, toks, cache,
-                                                *rest)
+                        logits, c = inner_chunk(need_logits, models, toks,
+                                                cache, *rest)
                     return logits, _pin(c, cache_sh)
             else:
-                def chunk_fn(need_logits, toks, cache, dcache, *rest):
+                def chunk_fn(need_logits, models, toks, cache, dcache,
+                             *rest):
                     with activation_mesh(mesh):
-                        logits, c, dc = inner_chunk(need_logits, toks,
-                                                    cache, dcache, *rest)
+                        logits, c, dc = inner_chunk(need_logits, models,
+                                                    toks, cache, dcache,
+                                                    *rest)
                     return logits, _pin(c, cache_sh), _pin(dc, draft_sh)
 
             inner_bind = bind_fn
@@ -708,25 +713,27 @@ class ContinuousEngine:
 
             inner_decode = decode_fn
 
-            def decode_fn(cache, state, key):
+            def decode_fn(models, cache, state, key):
                 with activation_mesh(mesh):
-                    c, st, nxt, done = inner_decode(cache, state, key)
+                    c, st, nxt, done = inner_decode(models, cache, state,
+                                                    key)
                 return _pin(c, cache_sh), _pin(st, state_sh), nxt, done
 
             if draft_model is not None:
                 inner_spec_draft = spec_draft_fn
 
-                def spec_draft_fn(dcache, vlen, state):
+                def spec_draft_fn(models, dcache, vlen, state):
                     with activation_mesh(mesh):
-                        dc, drafts = inner_spec_draft(dcache, vlen, state)
+                        dc, drafts = inner_spec_draft(models, dcache, vlen,
+                                                      state)
                     return _pin(dc, draft_sh), drafts
 
                 inner_spec_verify = spec_verify_fn
 
-                def spec_verify_fn(cache, state, drafts):
+                def spec_verify_fn(models, cache, state, drafts):
                     with activation_mesh(mesh):
                         c, st, g, m, n_acc, done = inner_spec_verify(
-                            cache, state, drafts)
+                            models, cache, state, drafts)
                     return (_pin(c, cache_sh), _pin(st, state_sh), g, m,
                             n_acc, done)
 
@@ -734,17 +741,18 @@ class ContinuousEngine:
         # buckets bound how many widths ever occur).  Mid-prompt chunks use
         # the logits-free variant — only a prompt's FINAL chunk pays the
         # final-norm + vocab-projection matmul
-        chunk_donate = (1,) if draft_model is None else (1, 2)
+        self._models = (model, draft_model)
+        chunk_donate = (2,) if draft_model is None else (2, 3)
         self._chunk_last = jax.jit(
             lambda *a: chunk_fn(True, *a), donate_argnums=chunk_donate)
         self._chunk_mid = jax.jit(
             lambda *a: chunk_fn(False, *a), donate_argnums=chunk_donate)
         self._bind = jax.jit(bind_fn, donate_argnums=(0,))
-        self._decode = jax.jit(decode_fn, donate_argnums=(0, 1))
+        self._decode = jax.jit(decode_fn, donate_argnums=(1, 2))
         if draft_model is not None:
-            self._spec_draft = jax.jit(spec_draft_fn, donate_argnums=(0,))
+            self._spec_draft = jax.jit(spec_draft_fn, donate_argnums=(1,))
             self._spec_verify = jax.jit(spec_verify_fn,
-                                        donate_argnums=(0, 1))
+                                        donate_argnums=(1, 2))
 
     # -- request intake ------------------------------------------------------
 
@@ -1103,7 +1111,7 @@ class ContinuousEngine:
         run = self._chunk_last if final else self._chunk_mid
         caches = ((self.cache,) if self.draft_cache is None
                   else (self.cache, self.draft_cache))
-        args = (self._put_host(toks), *caches,
+        args = (self._models, self._put_host(toks), *caches,
                 jnp.asarray(task.slot, jnp.int32),
                 jnp.asarray(task.consumed, jnp.int32),
                 jnp.asarray(l, jnp.int32))
@@ -1227,9 +1235,10 @@ class ContinuousEngine:
         if running and self.spec_k:
             self._flush_table()
             self.draft_cache, drafts = self._spec_draft(
-                self.draft_cache, self.cache.length, self.state)
+                self._models, self.draft_cache, self.cache.length,
+                self.state)
             self.cache, self.state, g, m, n_acc, done = self._spec_verify(
-                self.cache, self.state, drafts)
+                self._models, self.cache, self.state, drafts)
             g_np, m_np = np.asarray(g), np.asarray(m)
             done_np = np.asarray(done)
             pos_np = np.asarray(self.cache.length[0])
@@ -1246,7 +1255,7 @@ class ContinuousEngine:
         elif running:
             self._flush_table()
             self.cache, self.state, nxt, done = self._decode(
-                self.cache, self.state, self._next_key())
+                self._models, self.cache, self.state, self._next_key())
             nxt_np, done_np = np.asarray(nxt), np.asarray(done)
             pos_np = np.asarray(self.cache.length[0])
             for slot in running:

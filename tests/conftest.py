@@ -14,6 +14,11 @@ except ImportError:
 jax.config.update("jax_enable_x64", False)
 
 
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers", "slow: a long test (subprocess with forced host devices)")
+
+
 @pytest.fixture(scope="session")
 def key():
     return jax.random.PRNGKey(0)

@@ -87,10 +87,13 @@ def test_restore_casts_to_template_dtype(tmp_path):
     assert restored.w.dtype == jnp.bfloat16
 
 
-def test_train_driver_resume(tmp_path):
+def test_train_driver_resume(tmp_path, monkeypatch):
     """End-to-end: kill training mid-run, relaunch, confirm resume."""
     from repro.launch.train import main
 
+    # a cache placed from outside is left alone, so the driver does not
+    # switch on a persistent cache for the rest of this test process
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     ckpt = str(tmp_path / "run")
     rc = main(["--arch", "paper-tiny", "--reduced", "--steps", "6",
                "--batch", "4", "--seq", "16", "--ckpt-dir", ckpt,

@@ -1,8 +1,7 @@
 """Flash prefill-chunk Pallas kernel: parity + serving identity.
 
 Mirrors ``tests/test_paged_attention.py`` for the prefill side of the
-kernel matrix (the ``kernels-interpret`` CI job runs both with
-``REPRO_PALLAS_INTERPRET=1``):
+kernel matrix (interpret mode on CPU, chosen by platform):
 
 * kernel vs oracle — :func:`repro.kernels.chunk_attention` must match
   the pure-jnp :func:`repro.kernels.ref.chunk_attention_ref` across
@@ -49,8 +48,8 @@ def _make_case(seed, *, heads, kvh, hd, bs, n_table, w, offset, n_valid,
     n_blocks = n_table + extra_blocks
     kq, kk, kv, kc, kw = jax.random.split(jax.random.PRNGKey(seed), 5)
     q = jax.random.normal(kq, (w, heads, hd), dtype)
-    k_pool = jax.random.normal(kk, (n_blocks, bs, kvh, hd), dtype)
-    v_pool = jax.random.normal(kv, (n_blocks, bs, kvh, hd), dtype)
+    k_pool = jax.random.normal(kk, (kvh, n_blocks, bs, hd), dtype)
+    v_pool = jax.random.normal(kv, (kvh, n_blocks, bs, hd), dtype)
     k_chunk = jax.random.normal(kc, (w, kvh, hd), dtype)
     v_chunk = jax.random.normal(kw, (w, kvh, hd), dtype)
     need = -(-(offset + n_valid) // bs) if offset + n_valid else 0
@@ -139,7 +138,7 @@ def test_sentinel_hole_in_prefix_is_masked():
     q, kp, vp, table, kc, vc, off, nv = _make_case(
         31, heads=4, kvh=2, hd=8, bs=4, n_table=4, w=4, offset=12,
         n_valid=4)
-    table = table.at[1].set(kp.shape[0])  # hole at positions 4..7
+    table = table.at[1].set(kp.shape[1])  # hole at positions 4..7
     case = (q, kp, vp, table, kc, vc, off, nv)
     _assert_parity(case)
     # and the hole genuinely changed the answer
@@ -168,8 +167,10 @@ def test_dense_wrapper_matches_identity_table_oracle():
     poolv = jnp.pad(v_lane, ((0, pad), (0, 0), (0, 0)))
     n_table = (max_len + pad) // bs
     table = jnp.arange(n_table, dtype=jnp.int32)
-    yr = chunk_attention_ref(q, pool.reshape(n_table, bs, kvh, hd),
-                             poolv.reshape(n_table, bs, kvh, hd), table,
+    yr = chunk_attention_ref(q, pool.reshape(n_table, bs, kvh, hd)
+                             .transpose(2, 0, 1, 3),
+                             poolv.reshape(n_table, bs, kvh, hd)
+                             .transpose(2, 0, 1, 3), table,
                              kc_, vc_, jnp.int32(off), jnp.int32(w))
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-5,
                                rtol=1e-5)
@@ -219,8 +220,8 @@ def _layer():
 
 def _paged_cache(batch, n_blocks, bs, n_table):
     return PagedKVCache(
-        k=jnp.zeros((n_blocks, bs, KVH, HD)),
-        v=jnp.zeros((n_blocks, bs, KVH, HD)),
+        k=jnp.zeros((KVH, n_blocks, bs, HD)),
+        v=jnp.zeros((KVH, n_blocks, bs, HD)),
         table=jnp.full((batch, n_table), n_blocks, jnp.int32),
         length=jnp.zeros((batch,), jnp.int32))
 
@@ -237,7 +238,7 @@ def _dst(table_row, off, w, n_valid, bs, n_blocks):
 def _scan_paged(attn, cache, x, slot, chunk, kernel):
     """Feed x (1, plen, dim) through paged prefill_chunk in chunk-sized
     spans (engine-style: blocks allocated up front here)."""
-    plen, bs = x.shape[1], cache.k.shape[1]
+    plen, bs = x.shape[1], cache.k.shape[2]
     outs = []
     for off in range(0, plen, chunk):
         n = min(chunk, plen - off)
@@ -249,7 +250,7 @@ def _scan_paged(attn, cache, x, slot, chunk, kernel):
             span, cache, slot=jnp.int32(slot), offset=jnp.int32(off),
             n_valid=jnp.int32(n),
             dst=_dst(cache.table[slot], off, chunk, n, bs,
-                     cache.k.shape[0]),
+                     cache.k.shape[1]),
             prefill_kernel=kernel)
         outs.append(out[:, :n])
     return jnp.concatenate(outs, axis=1), cache

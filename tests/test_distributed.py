@@ -41,7 +41,8 @@ SCRIPT = textwrap.dedent("""
     ref_state, ref_metrics = jax.jit(step)(fresh_state(), batch)
 
     # --- sharded: dp=4 x tp=2 mesh, TP+FSDP+activation constraints ---
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     ms = model_shardings(model, mesh, fsdp=True)
     repl = NamedSharding(mesh, P())
     st_sh = TrainState(model=ms, opt=AdamWState(step=repl, m=ms, v=ms,

@@ -74,7 +74,7 @@ def test_psum_reduction_in_shard_map():
         out, st2 = compress_and_reduce(g, st, axis_name="dp")
         return out
 
-    from _compat import shard_map
+    from jax import shard_map
 
     out = shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=P())(g, st)
     np.testing.assert_allclose(np.asarray(out["g0"]), np.asarray(g["g0"]),

@@ -48,7 +48,7 @@ def test_collectives_in_scan_multiplied():
 
     x = jax.ShapeDtypeStruct((128, 256), jnp.float32)
     ws = jax.ShapeDtypeStruct((8, 256, 256), jnp.float32)
-    from _compat import shard_map
+    from jax import shard_map
 
     sm = shard_map(fn, mesh=mesh, in_specs=(P(), P()), out_specs=P())
     c = analyze(_hlo(sm, x, ws))

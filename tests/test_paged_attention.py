@@ -1,8 +1,8 @@
 """Fused Pallas paged-attention decode kernel: parity + serving identity.
 
-Three layers of guarantee, all running in interpret mode on CPU (the
-``kernels-interpret`` CI job forces it explicitly so the same tests keep
-kernel regressions visible without a TPU):
+Three layers of guarantee, all running in interpret mode on CPU (chosen
+by platform; ``tests/test_chip_compile.py`` compiles the same kernel for a
+described TPU):
 
 * kernel vs oracle — :func:`repro.kernels.paged_attention` must match the
   pure-jnp :func:`repro.kernels.ref.paged_attention_ref` AND the
@@ -46,8 +46,8 @@ def _make_case(seed, *, batch, heads, kvh, hd, bs, n_table, extra_blocks=2,
     n_blocks = batch * n_table + extra_blocks
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(kq, (batch, heads, hd), dtype)
-    k_pool = jax.random.normal(kk, (n_blocks, bs, kvh, hd), dtype)
-    v_pool = jax.random.normal(kv, (n_blocks, bs, kvh, hd), dtype)
+    k_pool = jax.random.normal(kk, (kvh, n_blocks, bs, hd), dtype)
+    v_pool = jax.random.normal(kv, (kvh, n_blocks, bs, hd), dtype)
     pos = rng.integers(0, n_table * bs, batch).astype(np.int32)
     table = np.full((batch, n_table), n_blocks, np.int32)
     perm = rng.permutation(n_blocks)
@@ -68,13 +68,14 @@ def _dense_gather_attend(q, k_pool, v_pool, table, pos):
                          for a in (q, k_pool, v_pool))
     table, pos = np.asarray(table), np.asarray(pos)
     batch, heads, hd = q.shape
-    nb, bs, kvh, _ = k_pool.shape
+    kvh, nb, bs, _ = k_pool.shape
     group = heads // kvh
     kpos = np.arange(table.shape[1] * bs)
     rows = np.minimum(table[:, kpos // bs] * bs + kpos[None, :] % bs,
                       nb * bs - 1)
-    gk = k_pool.reshape(nb * bs, kvh, hd)[rows]  # (batch, S, kvh, hd)
-    gv = v_pool.reshape(nb * bs, kvh, hd)[rows]
+    # head-major pool -> flat (nb * bs, kvh, hd) rows
+    gk = k_pool.transpose(1, 2, 0, 3).reshape(nb * bs, kvh, hd)[rows]
+    gv = v_pool.transpose(1, 2, 0, 3).reshape(nb * bs, kvh, hd)[rows]
     valid = kpos[None, :] <= pos[:, None]
     qg = q.reshape(batch, kvh, group, hd)
     logits = np.einsum("bkgd,bskd->bkgs", qg, gk) / np.sqrt(hd)
@@ -153,7 +154,7 @@ def test_sentinel_block_inside_window_is_masked():
     landed on."""
     q, kp, vp, table, pos = _make_case(19, batch=2, heads=4, kvh=2, hd=8,
                                        bs=4, n_table=4)
-    n_blocks = kp.shape[0]
+    n_blocks = kp.shape[1]
     table = table.at[0, 1].set(n_blocks)  # hole inside slot 0's window
     pos = pos.at[0].set(14)               # covers table entries 0..3
     y = paged_attention(q, kp, vp, table, pos)
@@ -172,7 +173,7 @@ def test_fully_masked_slot_emits_zeros_not_nan():
     poison anything with NaN."""
     q, kp, vp, table, pos = _make_case(23, batch=2, heads=4, kvh=2, hd=8,
                                        bs=4, n_table=3)
-    n_blocks = kp.shape[0]
+    n_blocks = kp.shape[1]
     table = table.at[1].set(n_blocks)
     y = paged_attention(q, kp, vp, table, pos)
     yr = paged_attention_ref(q, kp, vp, table, pos)
@@ -204,19 +205,6 @@ def test_kernel_validates_shapes():
         paged_attention(q, kp, vp[:, :, :, :4], table, pos)
     with pytest.raises(ValueError, match="batch"):
         paged_attention(q, kp, vp, table, pos[:1])
-
-
-def test_interpret_mode_env_override(monkeypatch):
-    """REPRO_PALLAS_INTERPRET forces interpret mode (the kernels-interpret
-    CI job's contract); unset, the CPU backend already selects it."""
-    from repro.kernels import default_interpret
-
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
-    assert default_interpret() == (jax.default_backend() != "tpu")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert default_interpret() is True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert default_interpret() == (jax.default_backend() != "tpu")
 
 
 # ---- serving identity through ContinuousEngine -------------------------------

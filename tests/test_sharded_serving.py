@@ -123,7 +123,7 @@ MATRIX_SCRIPT = textwrap.dedent(_PRELUDE + """
             assert eng.model.blocks.attn.q_proj.weight.sharding.spec \\
                 == P(None, None, "model")
             assert eng.cache.k.sharding.spec \\
-                == P(None, None, None, "model", None)
+                == P(None, "model", None, None, None)
         if dp > 1:
             assert eng.cache.table.sharding.spec == P("data", None)
             assert eng.cache.length.sharding.spec == P(None, "data")

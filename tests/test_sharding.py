@@ -175,30 +175,30 @@ def _leaf(*shape):
 
 
 def test_paged_cache_specs():
-    # pool (L, n_blocks, bs, kvh, hd): blocks GLOBAL over data (the host
+    # pool (L, kvh, n_blocks, bs, hd): blocks GLOBAL over data (the host
     # allocator is placement-free), kv heads over "model"; table/length
     # shard their batch dim over data
-    cache = PagedKVCache(k=_leaf(2, 64, 8, 16, 64), v=_leaf(2, 64, 8, 16, 64),
+    cache = PagedKVCache(k=_leaf(2, 16, 64, 8, 64), v=_leaf(2, 16, 64, 8, 64),
                          table=_leaf(32, 16), length=_leaf(2, 32))
     specs = cache_specs(cache, POD)
-    assert specs.k == P(None, None, None, "model", None)
-    assert specs.v == P(None, None, None, "model", None)
+    assert specs.k == P(None, "model", None, None, None)
+    assert specs.v == P(None, "model", None, None, None)
     assert specs.table == P("data", None)
     assert specs.length == P(None, "data")
 
 
 def test_paged_cache_specs_gqa_fallback():
     # kv_heads=3 does not divide model=16 → pool replicates entirely
-    cache = PagedKVCache(k=_leaf(2, 64, 8, 3, 64), v=_leaf(2, 64, 8, 3, 64),
+    cache = PagedKVCache(k=_leaf(2, 3, 64, 8, 64), v=_leaf(2, 3, 64, 8, 64),
                          table=_leaf(32, 16), length=_leaf(2, 32))
     specs = cache_specs(cache, POD)
     assert specs.k == P(None, None, None, None, None)
     assert specs.table == P("data", None)
     # odd batch → table and length replicate but heads still shard
-    cache = PagedKVCache(k=_leaf(2, 64, 8, 16, 64), v=_leaf(2, 64, 8, 16, 64),
+    cache = PagedKVCache(k=_leaf(2, 16, 64, 8, 64), v=_leaf(2, 16, 64, 8, 64),
                          table=_leaf(33, 16), length=_leaf(2, 33))
     specs = cache_specs(cache, POD)
-    assert specs.k == P(None, None, None, "model", None)
+    assert specs.k == P(None, "model", None, None, None)
     assert specs.table == P(None, None)
     assert specs.length == P(None, None)
 

@@ -39,6 +39,68 @@ from repro.serve.paging import PagedCacheManager
 from repro.serve.sampling import greedy_tokens, sample_tokens
 from repro.serve.scheduler import Completion, Request, Scheduler
 
+# -- telemetry: host spans and compile accounting -----------------------------
+
+#: the engine whose ``step()`` is open on this thread and the ``serve.*``
+#: span open inside it: the one compile listener charges compiles to them
+_ON_THREAD = threading.local()
+#: fires once per program a jit obtains, compiled or loaded from the cache
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_listener_lock = threading.Lock()
+_listening = False
+#: requests per wait reservoir (queue wait, prefill wait), as ServeMetrics
+_WAIT_RESERVOIR = 4096
+
+
+def _on_compile(event: str, secs: float, **kw) -> None:
+    engine = getattr(_ON_THREAD, "engine", None)
+    if event == _COMPILE_EVENT and engine is not None:
+        engine._programs_compiled += 1
+        engine._step_compiles.append(_ON_THREAD.phase)
+
+
+def _listen_for_compiles() -> None:
+    """Register the process-wide compile listener, once."""
+    global _listening
+    with _listener_lock:
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(_on_compile)
+            _listening = True
+
+
+class _span:
+    """A ``serve.*`` host span: a ``jax.profiler.TraceAnnotation`` (a
+    profile records it on the device trace's clock, so a device idle gap
+    can be put down to the span open at its middle) that also names the
+    phase a compile on this thread is charged to.  ``engine`` marks the
+    outermost span, ``serve.step``, whose engine the compiles count in."""
+
+    __slots__ = ("name", "engine", "_ann", "_prev")
+
+    def __init__(self, name: str, engine=None):
+        self.name, self.engine = name, engine
+
+    def __enter__(self):
+        self._prev = (getattr(_ON_THREAD, "phase", None),
+                      getattr(_ON_THREAD, "engine", None))
+        _ON_THREAD.phase = self.name
+        if self.engine is not None:
+            _ON_THREAD.engine = self.engine
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        _ON_THREAD.phase, _ON_THREAD.engine = self._prev
+
+
+def _p50_p95(values) -> Tuple[float, float]:
+    if not values:
+        return 0.0, 0.0
+    p50, p95 = np.percentile(np.asarray(values, np.float64), (50, 95))
+    return float(p50), float(p95)
+
 
 def generate(model, tokens: jax.Array, cache, *, n_steps: int,
              temperature: float = 0.0, key: Optional[jax.Array] = None):
@@ -497,6 +559,16 @@ class ContinuousEngine:
         # prefill accounting (prefill_stats() / benchmarks); bounded like
         # scheduler.admitted so a long-lived server cannot leak step dicts
         self.step_log: deque = deque(maxlen=65536)
+        # request waits (seconds) of each request's first life: submit to
+        # admission, admission to first token
+        self._queue_waits: deque = deque(maxlen=_WAIT_RESERVOIR)
+        self._prefill_waits: deque = deque(maxlen=_WAIT_RESERVOIR)
+        self._steps = 0
+        self._decode_steps = 0
+        self._mixed_steps = 0  # decode steps that also ran >= 1 chunk
+        self._programs_compiled = 0  # on the thread running step()
+        self._step_compiles: list = []  # the phase of each, this step
+        _listen_for_compiles()
         self._prompt_tokens_admitted = 0
         self._prefill_tokens_computed = 0  # true prompt tokens run
         self._prefill_tokens_padded = 0    # bucket widths run (compute cost)
@@ -517,16 +589,23 @@ class ContinuousEngine:
         # Every jitted role takes the weights as its first argument,
         # ``models = (model, draft_model)``: weights a jitted function
         # closes over are baked into its compiled program as constants —
-        # one copy of the whole model per program on the device.
+        # one copy of the whole model per program on the device.  Each
+        # role's body runs under a ``serve.*`` named scope, so its device
+        # ops carry the role in their op path.
+        def chunk_scope(need_logits):
+            return "serve.chunk_last" if need_logits else "serve.chunk_mid"
+
         if draft_model is None:
             def chunk_fn(need_logits, models, toks, cache, slot, offset,
                          n_valid, dst=None):
                 model = models[0]
                 kw = {} if dst is None else {"dst": dst}
-                return model.prefill_chunk(toks, cache, slot=slot,
-                                           offset=offset, n_valid=n_valid,
-                                           need_logits=need_logits,
-                                           **pk_kw, **kw)
+                with jax.named_scope(chunk_scope(need_logits)):
+                    return model.prefill_chunk(toks, cache, slot=slot,
+                                               offset=offset,
+                                               n_valid=n_valid,
+                                               need_logits=need_logits,
+                                               **pk_kw, **kw)
         else:
             # the draft prefills the same chunk into its own cache (logits
             # never needed — the verifier's final chunk seeds the first
@@ -535,14 +614,17 @@ class ContinuousEngine:
                          offset, n_valid, dst=None):
                 model, draft_model = models
                 kw = {} if dst is None else {"dst": dst}
-                logits, cache = model.prefill_chunk(
-                    toks, cache, slot=slot, offset=offset, n_valid=n_valid,
-                    need_logits=need_logits, **pk_kw, **kw)
-                _, dcache = draft_model.prefill_chunk(
-                    toks, dcache, slot=slot, offset=offset, n_valid=n_valid,
-                    need_logits=False, **pk_kw, **kw)
+                with jax.named_scope(chunk_scope(need_logits)):
+                    logits, cache = model.prefill_chunk(
+                        toks, cache, slot=slot, offset=offset,
+                        n_valid=n_valid, need_logits=need_logits, **pk_kw,
+                        **kw)
+                    _, dcache = draft_model.prefill_chunk(
+                        toks, dcache, slot=slot, offset=offset,
+                        n_valid=n_valid, need_logits=False, **pk_kw, **kw)
                 return logits, cache, dcache
 
+        @jax.named_scope("serve.bind")
         def bind_fn(state, slot, logits, length, temp, max_new, stop_row,
                     key):
             first = sample_tokens(logits, temp[None], key)[0]
@@ -571,6 +653,7 @@ class ContinuousEngine:
 
         stateful = self.cache_kind != "kv"
 
+        @jax.named_scope("serve.decode")
         def decode_fn(models, cache, state, key):
             logits, new_cache = model_decode(models[0], state.tok[:, None],
                                              cache)
@@ -600,6 +683,7 @@ class ContinuousEngine:
                                    n_gen=n_gen)
             return new_cache, state, nxt, done
 
+        @jax.named_scope("serve.spec_draft")
         def spec_draft_fn(models, dcache, vlen, state):
             """Draft ``spec_k`` greedy tokens per slot with the factorized
             model (cheap single-token steps).  The draft frontier is synced
@@ -622,6 +706,7 @@ class ContinuousEngine:
                 body, (state.tok, dcache), None, length=spec_k)
             return dcache, drafts.T  # (B, k)
 
+        @jax.named_scope("serve.spec_verify")
         def spec_verify_fn(models, cache, state, drafts):
             """Verify ``spec_k`` drafted tokens in ONE dense multi-token
             decode and commit the agreeing prefix + one correction token.
@@ -842,7 +927,9 @@ class ContinuousEngine:
                 self._rewind_dependents(orphans)
 
     def _flush_table(self) -> None:
-        if self.manager is not None and self._table_dirty:
+        if self.manager is None or not self._table_dirty:
+            return
+        with _span("serve.table_flush"):
             self.cache = self.cache._replace(
                 table=self._put_table(self.manager.tables,
                                       draft=False))
@@ -1082,6 +1169,8 @@ class ContinuousEngine:
             req=req, slot=slot, seq=self._admit_seq, plen=plen,
             cached=cached, consumed=start, hit_bids=hit_bids)
         self.scheduler.begin_prefill(slot, req)
+        if req.uid not in self._resume_state:  # a resume is no new wait
+            self._queue_waits.append(req.admitted_at - req.submitted_at)
         self._prompt_tokens_admitted += plen
         self._prefix_skipped_tokens += start
         # park the slot's write frontier out of range: the batched decode
@@ -1105,35 +1194,36 @@ class ContinuousEngine:
     def _run_chunk(self, task: _PrefillTask, l: int, w: int) -> int:
         """Feed one bucket-padded chunk of extent ``(l, w)`` (from
         :meth:`_chunk_extent`); returns the padded width spent."""
-        toks = np.zeros((1, w), np.int32)
-        toks[0, :l] = task.req.prompt[task.consumed:task.consumed + l]
-        final = task.consumed + l >= task.plen
-        run = self._chunk_last if final else self._chunk_mid
-        caches = ((self.cache,) if self.draft_cache is None
-                  else (self.cache, self.draft_cache))
-        args = (self._models, self._put_host(toks), *caches,
-                jnp.asarray(task.slot, jnp.int32),
-                jnp.asarray(task.consumed, jnp.int32),
-                jnp.asarray(l, jnp.int32))
-        if self.manager is not None:
-            dst = self.manager.scatter_rows(task.slot, task.consumed, w,
-                                            lo=task.cached, hi=task.plen)
-            out = run(*args, self._put_host(dst))
-        else:
-            out = run(*args)
-        if self.draft_cache is None:
-            logits, self.cache = out
-        else:
-            logits, self.cache, self.draft_cache = out
-        if final:
-            task.logits = logits
-        task.consumed += l
-        task.chunks += 1
-        if self.manager is not None:
-            self.manager.publish(task.slot, task.consumed)
-        self._prefill_tokens_computed += l
-        self._prefill_tokens_padded += w
-        self._prefill_chunks += 1
+        with _span("serve.prefill_chunk"):
+            toks = np.zeros((1, w), np.int32)
+            toks[0, :l] = task.req.prompt[task.consumed:task.consumed + l]
+            final = task.consumed + l >= task.plen
+            run = self._chunk_last if final else self._chunk_mid
+            caches = ((self.cache,) if self.draft_cache is None
+                      else (self.cache, self.draft_cache))
+            args = (self._models, self._put_host(toks), *caches,
+                    jnp.asarray(task.slot, jnp.int32),
+                    jnp.asarray(task.consumed, jnp.int32),
+                    jnp.asarray(l, jnp.int32))
+            if self.manager is not None:
+                dst = self.manager.scatter_rows(task.slot, task.consumed, w,
+                                                lo=task.cached, hi=task.plen)
+                out = run(*args, self._put_host(dst))
+            else:
+                out = run(*args)
+            if self.draft_cache is None:
+                logits, self.cache = out
+            else:
+                logits, self.cache, self.draft_cache = out
+            if final:
+                task.logits = logits
+            task.consumed += l
+            task.chunks += 1
+            if self.manager is not None:
+                self.manager.publish(task.slot, task.consumed)
+            self._prefill_tokens_computed += l
+            self._prefill_tokens_padded += w
+            self._prefill_chunks += 1
         return w
 
     def _put_host(self, arr) -> jax.Array:
@@ -1149,25 +1239,28 @@ class ContinuousEngine:
     def _complete_prefill(self, task: _PrefillTask) -> list:
         """Sample the first token from the final chunk's logits and move
         the slot into the decode batch (possibly finishing immediately)."""
-        req = task.req
-        stop_row = np.full((self.max_stop_ids,), -1, np.int32)
-        stop_row[:len(req.stop_ids)] = req.stop_ids
-        self.state, first, done0 = self._bind(
-            self.state, jnp.asarray(task.slot, jnp.int32), task.logits,
-            jnp.asarray(task.plen, jnp.int32),
-            jnp.asarray(req.temperature, jnp.float32),
-            jnp.asarray(req.max_new_tokens, jnp.int32),
-            jnp.asarray(stop_row), self._next_key())
-        del self._prefills[task.slot]
-        self.scheduler.bind(task.slot, req, int(first))
-        if req.uid in self._resume_state:
-            self._resumes += 1  # resumed life: its bind is not a real TTFT
-        else:
-            self.recent_ttfts.append(time.monotonic() - req.submitted_at)
-        self._emit(req.uid, int(first))
-        if bool(done0):
-            return [self._finish(task.slot, task.plen)]
-        return []
+        with _span("serve.bind"):
+            req = task.req
+            stop_row = np.full((self.max_stop_ids,), -1, np.int32)
+            stop_row[:len(req.stop_ids)] = req.stop_ids
+            self.state, first, done0 = self._bind(
+                self.state, jnp.asarray(task.slot, jnp.int32), task.logits,
+                jnp.asarray(task.plen, jnp.int32),
+                jnp.asarray(req.temperature, jnp.float32),
+                jnp.asarray(req.max_new_tokens, jnp.int32),
+                jnp.asarray(stop_row), self._next_key())
+            del self._prefills[task.slot]
+            self.scheduler.bind(task.slot, req, int(first))
+            if req.uid in self._resume_state:
+                self._resumes += 1  # resumed life: not a real TTFT
+            else:
+                first_at = self.scheduler.slots[task.slot].first_token_at
+                self.recent_ttfts.append(first_at - req.submitted_at)
+                self._prefill_waits.append(first_at - req.admitted_at)
+            self._emit(req.uid, int(first))
+            if bool(done0):
+                return [self._finish(task.slot, task.plen)]
+            return []
 
     def _advance_prefills(self) -> Tuple[list, int]:
         """Run chunks round-robin under the step budget, ROTATING the
@@ -1206,70 +1299,95 @@ class ContinuousEngine:
         """One scheduling round: apply cancels, admit, chunk prefills
         under the budget, bind finished prefills, then one batched decode
         step.  Returns the :class:`Completion`s finished this step
-        (cancelled ones included)."""
+        (cancelled ones included).  Each phase runs under its ``serve.*``
+        host span (see ``repro/serve/README.md``)."""
         t0 = time.monotonic()
         self._step_events = []
-        if self.prefill_budget_hook is not None:
-            try:
-                budget = self.prefill_budget_hook(self)
-                if budget is not None:
-                    self.prefill_chunk_budget = max(1, int(budget))
-            except Exception as exc:
-                # an operator hook bug must not take serving down
-                self.hook_errors.append(repr(exc))
-        finished = self._drain_cancels()
-        finished.extend(self.scheduler.expire_pending())
-        if self.preemption:
-            self._maybe_preempt()
-        while (adm := self._next_admission()) is not None:
-            self._begin_prefill(*adm)
-        prefill_spent = 0
-        if self._prefills:
-            self._flush_table()
-            done, prefill_spent = self._advance_prefills()
-            finished.extend(done)
-            self._max_step_prefill_tokens = max(
-                self._max_step_prefill_tokens, prefill_spent)
+        self._step_compiles = []
+        chunks0 = self._prefill_chunks
+        with _span("serve.step", engine=self):
+            if self.prefill_budget_hook is not None:
+                try:
+                    budget = self.prefill_budget_hook(self)
+                    if budget is not None:
+                        self.prefill_chunk_budget = max(1, int(budget))
+                except Exception as exc:
+                    # an operator hook bug must not take serving down
+                    self.hook_errors.append(repr(exc))
+            with _span("serve.admit"):
+                finished = self._drain_cancels()
+                finished.extend(self.scheduler.expire_pending())
+                if self.preemption:
+                    self._maybe_preempt()
+                while (adm := self._next_admission()) is not None:
+                    self._begin_prefill(*adm)
+            prefill_spent = 0
+            if self._prefills:
+                self._flush_table()
+                done, prefill_spent = self._advance_prefills()
+                finished.extend(done)
+                self._max_step_prefill_tokens = max(
+                    self._max_step_prefill_tokens, prefill_spent)
 
-        running = self.scheduler.running_slots()
-        if running and self.spec_k:
-            self._flush_table()
-            self.draft_cache, drafts = self._spec_draft(
-                self._models, self.draft_cache, self.cache.length,
-                self.state)
-            self.cache, self.state, g, m, n_acc, done = self._spec_verify(
-                self._models, self.cache, self.state, drafts)
-            g_np, m_np = np.asarray(g), np.asarray(m)
-            done_np = np.asarray(done)
-            pos_np = np.asarray(self.cache.length[0])
-            self._spec_rounds += 1
-            self._spec_drafted += self.spec_k * len(running)
-            self._spec_accepted += int(np.asarray(n_acc).sum())
-            for slot in running:
-                uid = self.scheduler.slots[slot].request.uid
-                for tok in g_np[slot, :m_np[slot]]:
-                    self.scheduler.append_token(slot, tok)
-                    self._emit(uid, tok)
-                if done_np[slot]:
-                    finished.append(self._finish(slot, int(pos_np[slot])))
-        elif running:
-            self._flush_table()
-            self.cache, self.state, nxt, done = self._decode(
-                self._models, self.cache, self.state, self._next_key())
-            nxt_np, done_np = np.asarray(nxt), np.asarray(done)
-            pos_np = np.asarray(self.cache.length[0])
-            for slot in running:
-                self.scheduler.append_token(slot, nxt_np[slot])
-                self._emit(self.scheduler.slots[slot].request.uid,
-                           nxt_np[slot])
-                if done_np[slot]:
-                    finished.append(self._finish(slot, int(pos_np[slot])))
-        self.step_log.append({
-            "wall_s": time.monotonic() - t0,
-            "prefill_tokens": prefill_spent,
-            "decoded": bool(running),
-        })
-        return [self._merge_resume(c) for c in finished]
+            running = self.scheduler.running_slots()
+            if running and self.spec_k:
+                self._flush_table()
+                with _span("serve.decode"):
+                    self.draft_cache, drafts = self._spec_draft(
+                        self._models, self.draft_cache, self.cache.length,
+                        self.state)
+                    self.cache, self.state, g, m, n_acc, done = \
+                        self._spec_verify(self._models, self.cache,
+                                          self.state, drafts)
+                with _span("serve.host_sync"):
+                    g_np, m_np = np.asarray(g), np.asarray(m)
+                    done_np = np.asarray(done)
+                    pos_np = np.asarray(self.cache.length[0])
+                    accepted = int(np.asarray(n_acc).sum())
+                self._spec_rounds += 1
+                self._spec_drafted += self.spec_k * len(running)
+                self._spec_accepted += accepted
+                with _span("serve.emit"):
+                    for slot in running:
+                        uid = self.scheduler.slots[slot].request.uid
+                        for tok in g_np[slot, :m_np[slot]]:
+                            self.scheduler.append_token(slot, tok)
+                            self._emit(uid, tok)
+                        if done_np[slot]:
+                            finished.append(
+                                self._finish(slot, int(pos_np[slot])))
+            elif running:
+                self._flush_table()
+                with _span("serve.decode"):
+                    self.cache, self.state, nxt, done = self._decode(
+                        self._models, self.cache, self.state,
+                        self._next_key())
+                with _span("serve.host_sync"):
+                    nxt_np, done_np = np.asarray(nxt), np.asarray(done)
+                    pos_np = np.asarray(self.cache.length[0])
+                with _span("serve.emit"):
+                    for slot in running:
+                        self.scheduler.append_token(slot, nxt_np[slot])
+                        self._emit(self.scheduler.slots[slot].request.uid,
+                                   nxt_np[slot])
+                        if done_np[slot]:
+                            finished.append(
+                                self._finish(slot, int(pos_np[slot])))
+            chunks = self._prefill_chunks - chunks0
+            self._steps += 1
+            if running:
+                self._decode_steps += 1
+                if chunks:
+                    self._mixed_steps += 1
+            self.step_log.append({
+                "t0": t0,
+                "wall_s": time.monotonic() - t0,
+                "prefill_tokens": prefill_spent,
+                "chunks": chunks,
+                "decoded_slots": len(running),
+                "compiles": self._step_compiles,
+            })
+            return [self._merge_resume(c) for c in finished]
 
     # -- introspection -------------------------------------------------------
 
@@ -1354,7 +1472,28 @@ class ContinuousEngine:
 
     def prefill_stats(self) -> dict:
         """Admission-path accounting: how much prompt compute actually ran
-        (vs was skipped via prefix hits) and how bursty it was per step."""
+        (vs was skipped via prefix hits) and how bursty it was per step;
+        where the wait for the first token went; how many steps decoded,
+        and how many of those also ran a prefill chunk (``mixed_steps``);
+        and how many programs the thread running ``step()`` compiled or
+        loaded from the compile cache.
+
+        Queue wait is submit to admission (a slot), prefill wait is
+        admission to the first token, each over the first life of the
+        requests of a bounded reservoir since :meth:`reset_stats`.  A
+        request still pending, or admitted and not yet bound, enters with
+        its wait so far.  Safe to call from another thread while
+        ``step()`` runs: live containers are copied in one call each."""
+        now = time.monotonic()
+        resumed = set(self._resume_state)
+        pending = self.scheduler.pending
+        prefilling = tuple(self.scheduler.prefilling.values())
+        queue = list(self._queue_waits) + [
+            now - r.submitted_at for r in pending if r.uid not in resumed]
+        prefill = list(self._prefill_waits) + [
+            now - r.admitted_at for r in prefilling if r.uid not in resumed]
+        q50, q95 = _p50_p95(queue)
+        p50, p95 = _p50_p95(prefill)
         admitted = self._prompt_tokens_admitted
         return {
             "chunk_size": self.chunk_size,
@@ -1369,6 +1508,14 @@ class ContinuousEngine:
             "prefill_chunks": self._prefill_chunks,
             "max_step_prefill_tokens": self._max_step_prefill_tokens,
             "prefill_kernel": self.prefill_kernel,
+            "queue_wait_p50_s": q50,
+            "queue_wait_p95_s": q95,
+            "prefill_wait_p50_s": p50,
+            "prefill_wait_p95_s": p95,
+            "steps": self._steps,
+            "decode_steps": self._decode_steps,
+            "mixed_steps": self._mixed_steps,
+            "programs_compiled": self._programs_compiled,
         }
 
     def spec_stats(self) -> dict:
@@ -1411,6 +1558,12 @@ class ContinuousEngine:
         self._resumes = 0
         self._preempt_violations = 0
         self.recent_ttfts.clear()
+        self._queue_waits.clear()
+        self._prefill_waits.clear()
+        self._steps = 0
+        self._decode_steps = 0
+        self._mixed_steps = 0
+        self._programs_compiled = 0
         self._prompt_tokens_admitted = 0
         self._prefill_tokens_computed = 0
         self._prefill_tokens_padded = 0

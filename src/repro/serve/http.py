@@ -65,6 +65,7 @@ from collections import deque
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 def _quantile(values, q: float) -> float:
@@ -193,6 +194,24 @@ class ServeMetrics:
                pf["prefill_tokens_computed"], type_="counter")
         metric("repro_serve_prompt_tokens_admitted_total",
                pf["prompt_tokens_admitted"], type_="counter")
+        for name, key, help_ in (
+                ("repro_serve_queue_wait_seconds", "queue_wait",
+                 "Submit to first slot, first life of each request"),
+                ("repro_serve_prefill_wait_seconds", "prefill_wait",
+                 "First slot to first token, first life of each request")):
+            metric(name, pf[f"{key}_p50_s"], help_,
+                   labels='{quantile="0.5"}')
+            metric(name, pf[f"{key}_p95_s"], labels='{quantile="0.95"}')
+        metric("repro_serve_steps_total", pf["decode_steps"],
+               "Engine steps that decoded (kind=decode), and those of them "
+               "that also ran a prefill chunk (kind=mixed)", "counter",
+               labels='{kind="decode"}')
+        metric("repro_serve_steps_total", pf["mixed_steps"],
+               labels='{kind="mixed"}')
+        metric("repro_serve_programs_compiled_total",
+               pf["programs_compiled"],
+               "Programs compiled or loaded from the compile cache while "
+               "the engine stepped", "counter")
 
         if engine.spec_k:
             sp = engine.spec_stats()
@@ -282,15 +301,16 @@ class HttpServer:
                         pass
                     continue
             done = await loop.run_in_executor(None, self.engine.step)
-            for uid, tok in self.engine.step_events:
-                route = self._routes.get(uid)
-                if route is not None:
-                    route.queue.put_nowait(("token", tok))
-            for comp in done:
-                self.metrics.observe(comp)
-                route = self._routes.pop(comp.uid, None)
-                if route is not None:
-                    route.queue.put_nowait(("done", comp))
+            with TraceAnnotation("serve.fanout"):
+                for uid, tok in self.engine.step_events:
+                    route = self._routes.get(uid)
+                    if route is not None:
+                        route.queue.put_nowait(("token", tok))
+                for comp in done:
+                    self.metrics.observe(comp)
+                    route = self._routes.pop(comp.uid, None)
+                    if route is not None:
+                        route.queue.put_nowait(("done", comp))
 
     # -- HTTP ----------------------------------------------------------------
 

@@ -82,6 +82,7 @@ class Request:
     timeout_s: Optional[float] = None  # relative deadline (None = none)
     uid: int = field(default_factory=lambda: next(_uid_counter))
     submitted_at: float = 0.0  # stamped by Scheduler.submit
+    admitted_at: float = 0.0   # first slot, by Scheduler.begin_prefill
     deadline: float = 0.0      # absolute monotonic; 0 = none
 
     def __post_init__(self):
@@ -101,9 +102,11 @@ class Request:
 class Completion:
     """A finished request: generated tokens + lifecycle timestamps.
 
-    ``first_token_at`` is 0.0 for a request cancelled before its first
-    token landed (``ttft`` is NaN there — stats reducers skip such
-    completions).  ``preemptions`` counts how many times the request was
+    ``admitted_at`` is when the request first got a slot (a resume after
+    preemption keeps it; 0.0 if it never got one).  ``first_token_at`` is
+    0.0 for a request cancelled before its first token landed (``ttft``
+    is NaN there — stats reducers skip such completions).
+    ``preemptions`` counts how many times the request was
     preempted and resumed before finishing (its ``tokens`` are the full
     merged stream across lives)."""
 
@@ -113,6 +116,7 @@ class Completion:
     finish_reason: str  # one of FINISH_REASONS
     priority: int = 1
     submitted_at: float = 0.0
+    admitted_at: float = 0.0
     first_token_at: float = 0.0
     finished_at: float = 0.0
     preemptions: int = 0
@@ -186,6 +190,7 @@ class Scheduler:
         else:
             request = dataclasses.replace(request)
         request.submitted_at = time.monotonic()
+        request.admitted_at = 0.0
         if request.timeout_s is not None:
             request.deadline = request.submitted_at + request.timeout_s
         self._seen_uids.add(request.uid)
@@ -330,6 +335,7 @@ class Scheduler:
             finish_reason="cancelled",
             priority=request.priority,
             submitted_at=request.submitted_at,
+            admitted_at=request.admitted_at,
             first_token_at=0.0,  # never produced one
             finished_at=time.monotonic(),
         )
@@ -353,9 +359,12 @@ class Scheduler:
 
     def begin_prefill(self, slot: int, request: Request) -> None:
         """Occupy ``slot`` for a request whose prompt is being chunked in;
-        the slot joins decode only at :meth:`bind`."""
+        the slot joins decode only at :meth:`bind`.  Stamps
+        ``admitted_at`` on the request's first admission."""
         assert self.slots[slot] is None, f"slot {slot} busy"
         assert slot not in self.prefilling, f"slot {slot} already prefilling"
+        if not request.admitted_at:
+            request.admitted_at = time.monotonic()
         self.prefilling[slot] = request
 
     def bind(self, slot: int, request: Request, first_token: int) -> None:
@@ -394,6 +403,7 @@ class Scheduler:
             finish_reason=reason,
             priority=s.request.priority,
             submitted_at=s.request.submitted_at,
+            admitted_at=s.request.admitted_at,
             first_token_at=s.first_token_at,
             finished_at=time.monotonic(),
         )

@@ -436,6 +436,45 @@ def test_http_metrics_expose_priority_and_preemption_series(server):
     _wait_drained(eng)
 
 
+def test_http_metrics_expose_wait_step_and_compile_families(server):
+    """The engine's own telemetry reaches the scrape: queue and prefill
+    wait quantiles, decode/mixed step counters, programs compiled."""
+    bg, eng = server[0], server[1]
+
+    async def drive():
+        rng = np.random.default_rng(13)
+        payload = {"prompt": rng.integers(0, 64, 20).tolist(),
+                   "max_new_tokens": 4}
+        r = await sse_generate(bg.host, bg.port, payload)
+        assert r["status"] == 200 and r["finish_reason"] == "length"
+        _, body = await fetch(bg.host, bg.port, "/metrics")
+        return body.decode()
+
+    text = asyncio.run(drive())
+    values = {}
+    for line in text.splitlines():
+        if line.startswith("repro_serve_") and not line.startswith("#"):
+            series, value = line.rsplit(" ", 1)
+            values[series] = float(value)
+    pf = eng.prefill_stats()
+    for family in ("queue_wait", "prefill_wait"):
+        p50 = values[f'repro_serve_{family}_seconds{{quantile="0.5"}}']
+        p95 = values[f'repro_serve_{family}_seconds{{quantile="0.95"}}']
+        assert 0 <= p50 <= p95
+    assert values['repro_serve_prefill_wait_seconds{quantile="0.95"}'] > 0
+    decode = values['repro_serve_steps_total{kind="decode"}']
+    mixed = values['repro_serve_steps_total{kind="mixed"}']
+    assert 0 <= mixed <= decode and decode >= 3
+    assert decode <= pf["decode_steps"]
+    assert values["repro_serve_programs_compiled_total"] >= 0
+    for name in ("repro_serve_queue_wait_seconds",
+                 "repro_serve_prefill_wait_seconds",
+                 "repro_serve_steps_total",
+                 "repro_serve_programs_compiled_total"):
+        assert f"# TYPE {name} " in text, name
+    _wait_drained(eng)
+
+
 def test_http_priority_payload_reaches_scheduler(setup):
     """A body ``"priority"`` rides through the route into the engine: a
     class-0 POST overtakes an earlier-queued default-class request.

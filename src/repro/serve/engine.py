@@ -566,6 +566,8 @@ class ContinuousEngine:
         self._steps = 0
         self._decode_steps = 0
         self._mixed_steps = 0  # decode steps that also ran >= 1 chunk
+        self._walk_entries = 0  # table entries the decode kernel walked
+        self._table_entries = 0  # of batch x max_table a decode step
         self._programs_compiled = 0  # on the thread running step()
         self._step_compiles: list = []  # the phase of each, this step
         _listen_for_compiles()
@@ -1365,6 +1367,8 @@ class ContinuousEngine:
                 with _span("serve.host_sync"):
                     nxt_np, done_np = np.asarray(nxt), np.asarray(done)
                     pos_np = np.asarray(self.cache.length[0])
+                    if self.manager is not None:
+                        self._count_walk(pos_np, running)
                 with _span("serve.emit"):
                     for slot in running:
                         self.scheduler.append_token(slot, nxt_np[slot])
@@ -1388,6 +1392,22 @@ class ContinuousEngine:
                 "compiles": self._step_compiles,
             })
             return [self._merge_resume(c) for c in finished]
+
+    def _count_walk(self, pos_np: np.ndarray, running) -> None:
+        """Add one decode step's paged-kernel walk to the
+        ``decode_walk_share`` counter, from the host's table mirror and
+        the positions just read: the running slots advanced by one, so
+        the kernel saw each one position earlier."""
+        # imported on first use, as the decode path imports the kernel:
+        # importing the engine loads no Pallas module
+        from repro.kernels.paged_attention import walk_bound
+
+        pos = pos_np.copy()
+        pos[running] -= 1
+        tables = self.manager.tables
+        self._walk_entries += int(walk_bound(
+            tables, pos, self.block_size, self.manager.sentinel).sum())
+        self._table_entries += tables.size
 
     # -- introspection -------------------------------------------------------
 
@@ -1419,7 +1439,13 @@ class ContinuousEngine:
         every aggregate number includes it.  In the paged layout the
         draft shares the verifier's block tables, so one block 'in use'
         pins rows in BOTH pools — per-block bytes cover the two pools
-        together."""
+        together.
+
+        ``decode_walk_share`` (paged) is how much of the block tables the
+        decode kernel walks: over the plain decode steps since
+        :meth:`reset_stats`, the sum of each slot's walk bound
+        (:func:`repro.kernels.paged_attention.walk_bound`) over the sum
+        of ``batch x max_table``; 0.0 before the first decode step."""
 
         def _leaf_bytes(cache):
             return sum(a.size * a.dtype.itemsize
@@ -1466,6 +1492,8 @@ class ContinuousEngine:
             "blocks_in_use": a.n_in_use,
             "blocks_retained": len(self.manager.retained),
             "prefix_hit_tokens": self.manager.prefix_hit_tokens,
+            "decode_walk_share": (self._walk_entries / self._table_entries
+                                  if self._table_entries else 0.0),
             "decode_kernel": self.decode_kernel,
             "prefill_kernel": self.prefill_kernel})
         return stats
@@ -1563,6 +1591,8 @@ class ContinuousEngine:
         self._steps = 0
         self._decode_steps = 0
         self._mixed_steps = 0
+        self._walk_entries = 0
+        self._table_entries = 0
         self._programs_compiled = 0
         self._prompt_tokens_admitted = 0
         self._prefill_tokens_computed = 0

@@ -56,15 +56,17 @@ def _compile(fn, kernel, *specs, sharding):
     assert pallas_kernels(compiled.as_text()) == {kernel}
 
 
-@pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-def test_paged_attention_compiles(one_chip, pool_dtype):
-    pool = (KVH, N_BLOCKS, BLOCK, HD)
+@pytest.mark.parametrize("pool_dtype,slots,n_table", [
+    (jnp.float32, SLOTS, N_TABLE), (jnp.bfloat16, SLOTS, N_TABLE),
+    # the chat benchmark's engine: 32 slots to 4,096 tokens
+    (jnp.bfloat16, 32, 4096 // BLOCK)], ids=["f32", "bf16", "bf16-chat"])
+def test_paged_attention_compiles(one_chip, pool_dtype, slots, n_table):
+    pool = (KVH, slots * n_table, BLOCK, HD)
     _compile(functools.partial(paged_attention, interpret=False),
-             "_paged_attention_call", ((SLOTS, HEADS, HD), jnp.bfloat16),
+             "_paged_attention_call", ((slots, HEADS, HD), jnp.bfloat16),
              (pool, pool_dtype), (pool, pool_dtype),
-             ((SLOTS, N_TABLE), jnp.int32),
-             ((SLOTS,), jnp.int32), sharding=one_chip)
+             ((slots, n_table), jnp.int32),
+             ((slots,), jnp.int32), sharding=one_chip)
 
 
 @pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16],

@@ -28,6 +28,7 @@ from _hyp import given, settings, st
 
 from repro.configs import get_config
 from repro.kernels import paged_attention, paged_attention_ref
+from repro.kernels.paged_attention import pages_per_block, walk_bound
 from repro.models import build_model
 from repro.serve import ContinuousEngine, make_trace, replay
 
@@ -141,6 +142,101 @@ def test_kernel_parity_random_layouts(seed):
         n_table=int(rng.integers(1, 7)),
         extra_blocks=int(rng.integers(0, 4)))
     _assert_three_way(q, kp, vp, table, pos)
+
+
+# ---- the walk bound ----------------------------------------------------------
+
+# bs 64 gives 8-page (512-key) compute blocks, so a 20-entry table is three
+# compute blocks (8, 8, 4): positions can sit mid-block or on a block edge
+WALK = dict(heads=4, kvh=2, hd=8, bs=64, n_table=20)
+
+
+def _walk_case(name):
+    """``(q, k_pool, v_pool, table, pos)`` of one walk-bound edge case."""
+    if name == "block_size_one":
+        return _make_case(5, batch=3, heads=4, kvh=2, hd=8, bs=1, n_table=9)
+    q, kp, vp, table, pos = _make_case(29, batch=4, **WALK)
+    nb, bs, nt = kp.shape[1], WALK["bs"], WALK["n_table"]
+    keys, park = pages_per_block(bs, nt) * bs, nt * bs
+    table, pos = np.array(table), np.array(pos)
+    # every slot owning its whole row, so that the position alone bounds it
+    full = np.random.default_rng(3).permutation(nb)[:table.size].reshape(
+        table.shape).astype(np.int32)
+    if name == "pos_mid_block":
+        table, pos[:] = full, [keys + 77, 5, 2 * keys + 40, keys // 2]
+    elif name == "pos_on_block_edge":
+        table, pos[:] = full, [keys - 1, keys, 2 * keys - 1, 2 * keys]
+    elif name == "ragged_table_width":   # every slot into the short block
+        table, pos[:] = full, [park - 1, 2 * keys + 1, park - 2,
+                               2 * keys + 3 * bs]
+    elif name == "released_slot":        # all sentinel, parked
+        table[1], pos[1] = nb, park
+    elif name == "parked_prefill":       # a 7-entry reservation, parked
+        table, pos[2] = full, park
+        table[2, 7:] = nb
+    elif name == "hole_in_bound":
+        table, pos[0] = full, 2 * keys + 5
+        table[0, keys // bs + 1] = nb
+    elif name == "all_idle":
+        table[:], pos[:] = nb, park
+    return q, kp, vp, jnp.asarray(table), jnp.asarray(pos)
+
+
+@pytest.mark.parametrize("name", [
+    "pos_mid_block", "pos_on_block_edge", "ragged_table_width",
+    "block_size_one", "released_slot", "parked_prefill", "hole_in_bound",
+    "all_idle"])
+def test_kernel_parity_walk_bound(name):
+    """The bounded walk equals the oracle wherever the bound falls: inside
+    or on the edge of a compute block, in a last block the table width
+    leaves short, at one-token blocks; a released slot and an all-idle
+    batch emit zeros, not NaN; a parked prefilling slot and a sentinel
+    hole inside the bound are masked as the oracle masks them."""
+    q, kp, vp, table, pos = _walk_case(name)
+    y = np.asarray(paged_attention(q, kp, vp, table, pos))
+    yr = np.asarray(paged_attention_ref(q, kp, vp, table, pos))
+    assert np.isfinite(y).all()
+    np.testing.assert_allclose(y, yr, atol=1e-5, rtol=1e-5)
+    walk = np.asarray(walk_bound(np.asarray(table), np.asarray(pos),
+                                 kp.shape[2], kp.shape[1]))
+    assert (y[walk == 0] == 0.0).all()
+    if name in ("released_slot", "all_idle"):
+        assert (walk == 0).sum() == (4 if name == "all_idle" else 1)
+    # the dense gather attends whatever a sentinel clips to: holes aside,
+    # every slot with a position in range matches it too
+    live = np.asarray(pos) < table.shape[1] * kp.shape[2]
+    if name == "hole_in_bound":
+        live[0] = False
+    np.testing.assert_allclose(
+        y[live], _dense_gather_attend(q, kp, vp, table, pos)[live],
+        atol=1e-5, rtol=1e-5, err_msg="kernel vs dense gather")
+
+
+def test_walk_bound_rows():
+    """One row per kind: a live slot stops at its position's block; a hole
+    stays inside the bound (it is the last mapped entry that counts); a
+    parked prefilling slot walks its reservation; an idle row walks
+    nothing; a full slot walks the whole table.  Host (numpy) and device
+    (jax) give the same bound."""
+    nb, bs, nt = 50, 4, 6
+    s = nb  # the sentinel
+    table = np.array([[3, 4, 5, s, s, s],     # live at pos 9 -> 3 entries
+                      [6, s, 7, 8, s, s],     # hole, pos 14 -> 4 entries
+                      [6, s, 7, 8, s, s],     # hole, pos 5 -> 2 entries
+                      [9, 10, s, s, s, s],    # parked prefill -> 2 entries
+                      [s, s, s, s, s, s],     # idle, parked -> 0
+                      [s, s, s, s, s, s],     # idle at pos 0 -> 0
+                      [0, 1, 2, 11, 12, 13]],  # full -> 6
+                     np.int32)
+    pos = np.array([9, 14, 5, nt * bs, nt * bs, 0, nt * bs - 1], np.int32)
+    want = [3, 4, 2, 2, 0, 0, 6]
+    np.testing.assert_array_equal(walk_bound(table, pos, bs, nb), want)
+    np.testing.assert_array_equal(
+        np.asarray(walk_bound(jnp.asarray(table), jnp.asarray(pos), bs, nb)),
+        want)
+    assert [pages_per_block(b, n) for b, n in
+            [(16, 256), (64, 20), (1, 9), (1024, 4), (16, 3)]] == \
+        [32, 8, 9, 1, 3]
 
 
 # ---- in-kernel masking -------------------------------------------------------

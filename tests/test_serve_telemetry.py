@@ -102,6 +102,27 @@ def test_preempted_resume_adds_no_wait_sample(setup):
         assert out[uid].admitted_at <= out[uid].first_token_at
 
 
+def test_decode_walk_share_counts_only_the_walked_table(setup):
+    """One request on three slots: the two idle slots walk no table entry,
+    and the running slot walks up to its position's block, so the share
+    is exactly that sum over ``batch x max_table`` a decode step."""
+    plen, max_new, bs = 20, 6, DIMS["block_size"]
+    eng = _engine(setup, batch=3)
+    assert eng.kv_stats()["decode_walk_share"] == 0.0  # no decode step yet
+    _submit(eng, [plen], setup[1].vocab, max_new=max_new)
+    eng.run()
+    steps = eng.prefill_stats()["decode_steps"]
+    assert steps == max_new - 1  # the first token comes from the prefill
+    reserved = -(-(plen + max_new) // bs)
+    walked = sum(min((plen + t) // bs + 1, reserved) for t in range(steps))
+    share = eng.kv_stats()["decode_walk_share"]
+    assert 0 < share <= 1
+    assert share == pytest.approx(
+        walked / (steps * 3 * eng.manager.max_table))
+    eng.reset_stats()
+    assert eng.kv_stats()["decode_walk_share"] == 0.0
+
+
 def test_mixed_steps_count_decode_steps_that_ran_a_chunk(setup):
     eng = _engine(setup)
     _submit(eng, [20, 5, 30, 3], setup[1].vocab, max_new=6)

@@ -22,6 +22,10 @@ factor boundary specs + ZeRO/FSDP fallbacks.
 ``constrain_acts`` is a no-op outside an ``activation_mesh`` context, so
 models call it unconditionally and single-device tests/benches never touch
 device state.
+
+``kernel_axes`` names the axes the serving kernels split over when they
+run shard-local, by the same rules as the pool's placement; ``mesh_of``
+finds the mesh a model's weights are committed to.
 """
 
 from __future__ import annotations
@@ -179,6 +183,33 @@ def cache_specs(cache, mesh):
     return jax.tree_util.tree_map(spec, cache)
 
 
+def kernel_axes(mesh, kv_heads: int, batch: int):
+    """``(heads, rows)``: the mesh axes the serving kernels split over
+    when they run shard-local (``repro.nn.attention``).  KV heads go over
+    "model" where they divide it, exactly as :func:`cache_specs` places
+    the pool's kv-head dim, so each model shard attends its own KV heads
+    with their query heads; slots go over the data axes where they divide
+    them, as the block tables do.  ``None`` where a dim stays whole (the
+    pool is replicated there, and every shard computes it alike)."""
+    tp = mesh.shape.get("model", 1)
+    data_axes = _data_axes(mesh)
+    dp = math.prod(mesh.shape[a] for a in data_axes) if data_axes else 1
+    heads = "model" if tp > 1 and kv_heads % tp == 0 else None
+    rows = _data_entry(data_axes) if dp > 1 and batch % dp == 0 else None
+    return heads, rows
+
+
+def mesh_of(tree):
+    """The mesh ``tree``'s arrays are committed to: that of the first leaf
+    placed by a ``NamedSharding`` over more than one device, else ``None``
+    (a model built or loaded on one device)."""
+    for leaf in jax.tree_util.tree_leaves(tree):
+        sh = getattr(leaf, "sharding", None)
+        if isinstance(sh, NamedSharding) and sh.mesh.size > 1:
+            return sh.mesh
+    return None
+
+
 def cache_shardings(cache, mesh):
     """Decode/prefill cache shardings: batch over data, heads over model.
 
@@ -243,5 +274,6 @@ def constrain_acts(x: jax.Array) -> jax.Array:
 
 
 __all__ = ["batch_spec", "spec_for_param", "model_shardings", "data_sharding",
-           "cache_specs", "cache_shardings", "activation_mesh",
-           "active_activation_mesh", "constrain_acts", "FSDP_MIN_SIZE"]
+           "cache_specs", "cache_shardings", "kernel_axes", "mesh_of",
+           "activation_mesh", "active_activation_mesh", "constrain_acts",
+           "FSDP_MIN_SIZE"]

@@ -43,7 +43,9 @@ W * group, head_dim)`` (row ``r = w * group + g``) and each grid step
 attends one KV head's query block.  The pool is head-major, ``(kv_heads,
 n_blocks, block_size, head_dim)``, so every fetched block is ``(block_size,
 head_dim)`` in its last two dims, as the TPU compiler's tiling requires
-(see :mod:`repro.kernels.paged_attention`).
+(see :mod:`repro.kernels.paged_attention`).  Under tensor parallelism it
+runs shard-local on one shard's KV heads and their query groups, like
+the decode kernel.
 """
 
 from __future__ import annotations

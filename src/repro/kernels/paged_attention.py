@@ -48,6 +48,12 @@ The pool is head-major so that each fetched block is ``(block_size,
 head_dim)`` in the last two dims: the TPU compiler requires a block's last
 two dims to be multiples of the ``(8, 128)`` tile (or the whole array
 dims), which a one-head slice in the second-minor place never is.
+
+Under tensor parallelism the kernel runs shard-local
+(``repro.nn.attention``, under ``shard_map``): each call sees one shard's
+KV heads and their query groups, and ``walk_bound`` takes ``n_blocks``
+from that local pool.  Blocks are global on every shard, so the local
+count is the global one and the table's sentinel means the same there.
 """
 
 from __future__ import annotations

@@ -17,8 +17,10 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from repro.dist.sharding import constrain_acts
+from repro.dist.sharding import (active_activation_mesh, constrain_acts,
+                                 kernel_axes)
 from repro.nn.linear import Linear
 from repro.nn.module import Module, static_field
 from repro.nn.rotary import apply_rope
@@ -28,6 +30,34 @@ from repro.nn.rotary import apply_rope
 # as a local literal because nn only imports repro.kernels lazily (pallas
 # must not load for training-only use)
 NEG_INF = -1e30
+
+
+def _local(kernel, kv_heads: int, batch: int, arg_dims, out_dims: str):
+    """``kernel`` as the traced step runs it.  Off a mesh, or on a mesh of
+    one device, that is ``kernel`` itself, called as is.  On a mesh (the
+    engine traces its steps inside ``activation_mesh``) it runs
+    shard-local under ``shard_map``: each model shard on its own KV heads
+    and the query heads that share them, each data shard on its own
+    slots (:func:`repro.dist.sharding.kernel_axes`).  The pool is
+    head-major and sharded on its KV heads, and ``q_proj`` is
+    column-parallel, so the query heads a shard holds are exactly the
+    groups of its KV heads: the kernel body sees an ordinary pool.
+
+    ``arg_dims`` gives one string per argument and ``out_dims`` one for
+    the result, a letter per dim: ``h`` for heads, ``b`` for slots, ``.``
+    for a dim every shard holds whole."""
+    active = active_activation_mesh()
+    if active is None or active[0].size == 1:
+        return kernel
+    mesh = active[0]
+    axes = dict(zip("hb", kernel_axes(mesh, kv_heads, batch)))
+
+    def spec(dims):
+        return P(*(axes.get(c) for c in dims))
+
+    return jax.shard_map(kernel, mesh=mesh,
+                         in_specs=tuple(spec(d) for d in arg_dims),
+                         out_specs=spec(out_dims), check_vma=False)
 
 
 class UnsupportedCacheError(ValueError):
@@ -358,7 +388,10 @@ class Attention(Module):
             if prefill_kernel == "pallas":
                 from repro.kernels.chunk_attention import chunk_attention
 
-                out = chunk_attention(
+                kernel = _local(chunk_attention, self.num_kv_heads, 1,
+                                (".h.", "h...", "h...", ".", ".h.", ".h.",
+                                 "", ""), ".h.")
+                out = kernel(
                     q[0], pool_k, pool_v, cache.table[slot],
                     k[0].astype(pool_k.dtype), v[0].astype(pool_v.dtype),
                     offset, n_valid).reshape(1, w, -1)
@@ -423,7 +456,10 @@ class Attention(Module):
                 from repro.kernels.chunk_attention import (
                     chunk_attention_dense)
 
-                out = chunk_attention_dense(
+                kernel = _local(chunk_attention_dense, self.num_kv_heads,
+                                1, (".h.", ".h.", ".h.", ".h.", ".h.", "",
+                                    ""), ".h.")
+                out = kernel(
                     q[0], new_k[slot], new_v[slot],
                     k[0].astype(cache.k.dtype), v[0].astype(cache.v.dtype),
                     offset, n_valid).reshape(1, w, -1)
@@ -583,7 +619,9 @@ class Attention(Module):
         if kernel == "pallas" and s == 1:
             from repro.kernels.paged_attention import paged_attention
 
-            out = paged_attention(q[:, 0], new_k, new_v, cache.table, pos)
+            kernel = _local(paged_attention, self.num_kv_heads, b,
+                            ("bh.", "h...", "h...", "b.", "b"), "bh.")
+            out = kernel(q[:, 0], new_k, new_v, cache.table, pos)
             out = out.reshape(b, 1, self.num_heads * self.head_dim)
         else:
             kpos = jnp.arange(max_table * bs)

@@ -21,6 +21,7 @@ See ``repro.serve.scheduler`` for the request lifecycle,
 from __future__ import annotations
 
 import dataclasses
+import math
 import threading
 import time
 from collections import deque
@@ -33,7 +34,7 @@ import numpy as np
 
 from repro.dist.runtime import global_config
 from repro.dist.sharding import (activation_mesh, cache_shardings,
-                                 data_sharding, model_shardings)
+                                 data_sharding, mesh_of, model_shardings)
 from repro.nn.attention import UnsupportedCacheError
 from repro.serve.paging import PagedCacheManager
 from repro.serve.sampling import greedy_tokens, sample_tokens
@@ -268,6 +269,15 @@ class ContinuousEngine:
     without a probe (whisper enc-dec) are rejected with a structured
     :class:`UnsupportedCacheError` naming the remaining ROADMAP item.
 
+    **Sharded serving** (``mesh``).  On a ``{data, model}`` mesh the
+    engine runs SPMD: weights by the Megatron rules, the paged pool's KV
+    heads over "model" (blocks stay global), slots over "data", and both
+    Pallas kernels shard-local (see ``repro/dist/README.md``).  Without
+    a ``mesh`` argument it serves on the mesh the model's weights are
+    committed to, if they span more than one device; each cache shard is
+    allocated on its own device, so no device ever holds the whole model
+    or pool.
+
     **Speculative decoding** (``draft_model`` + ``spec_k``).  The paper's
     low-rank factorized model (``auto_fact``) drafts ``spec_k`` tokens
     greedily with cheap single-token steps, then the dense model verifies
@@ -398,19 +408,6 @@ class ContinuousEngine:
                 "through the reference path)",
                 roadmap_item="make the kernels actually fast, and prove "
                 "it compiled")
-        if mesh is not None and mesh.shape.get("model", 1) > 1 \
-                and (decode_kernel == "pallas" or prefill_kernel == "pallas"):
-            # the fused kernels address the full kv-head dim per program;
-            # under tensor parallelism each model shard holds a head slice
-            # the kernels cannot see, so refuse instead of silently
-            # gathering the pool onto every shard
-            raise UnsupportedCacheError(
-                "decode_kernel/prefill_kernel='pallas' are single-shard "
-                "kernels; a mesh with model axis > 1 shards the KV heads "
-                "— use the reference kernels under tensor parallelism",
-                roadmap_item="make the kernels actually fast, and prove "
-                "it compiled (shard-local Pallas decode/prefill under "
-                "tensor parallelism)")
         if self.cache_kind != "kv":
             # ring / ssm / hybrid state cannot be paged or prefix-cached:
             # degrade gracefully to the per-slot layout (block reservation
@@ -441,6 +438,18 @@ class ContinuousEngine:
         self.batch, self.max_len = batch, max_len
         self.max_prompt_len, self.max_stop_ids = max_prompt_len, max_stop_ids
         self.kv_layout, self.cache_dtype = kv_layout, jnp.dtype(cache_dtype)
+        if mesh is None:
+            # a model committed to a multi-device mesh is served on it
+            mesh = mesh_of(model)
+
+        def alloc(make):
+            """Per-slot state from ``make``; on a mesh each device
+            allocates only its own shard of it."""
+            if mesh is None:
+                return make()
+            sh = cache_shardings(jax.eval_shape(make), mesh)
+            return jax.jit(make, out_shardings=sh)()
+
         if not hasattr(model, "prefill_chunk"):
             raise UnsupportedCacheError(
                 f"{type(model).__name__} has no chunked-prefill path; "
@@ -457,9 +466,9 @@ class ContinuousEngine:
                 raise UnsupportedCacheError(
                     f"{type(model).__name__} has no paged KV cache; the "
                     "paged layout supports attention-KV models only")
-            self.cache = model.init_paged_cache(
+            self.cache = alloc(lambda: model.init_paged_cache(
                 batch, max_len, cfg, n_blocks=self.n_blocks,
-                block_size=block_size, dtype=cache_dtype)
+                block_size=block_size, dtype=cache_dtype))
             retain = (self.n_blocks if prefix_retain_blocks is None
                       else prefix_retain_blocks)
             self.manager = PagedCacheManager(
@@ -470,9 +479,8 @@ class ContinuousEngine:
             self._park_pos = self.manager.max_table * block_size
         else:
             try:
-                self.cache = model.init_cache(batch, max_len, cfg,
-                                              dtype=cache_dtype,
-                                              per_slot=True)
+                self.cache = alloc(lambda: model.init_cache(
+                    batch, max_len, cfg, dtype=cache_dtype, per_slot=True))
             except TypeError:
                 raise UnsupportedCacheError(
                     f"{type(model).__name__} has no per-slot cache; "
@@ -487,12 +495,12 @@ class ContinuousEngine:
             # shares the SAME block tables (one allocation drives both
             # pools), so reservation/refcount bookkeeping stays single
             if kv_layout == "paged":
-                self.draft_cache = draft_model.init_paged_cache(
+                self.draft_cache = alloc(lambda: draft_model.init_paged_cache(
                     batch, max_len, cfg, n_blocks=self.n_blocks,
-                    block_size=block_size, dtype=cache_dtype)
+                    block_size=block_size, dtype=cache_dtype))
             else:
-                self.draft_cache = draft_model.init_cache(
-                    batch, max_len, cfg, dtype=cache_dtype, per_slot=True)
+                self.draft_cache = alloc(lambda: draft_model.init_cache(
+                    batch, max_len, cfg, dtype=cache_dtype, per_slot=True))
         else:
             self.draft_cache = None
         self.state = _SlotArrays(
@@ -506,26 +514,26 @@ class ContinuousEngine:
         self.mesh = mesh
         if mesh is not None:
             # Mesh-native placement, done ONCE at construction: params via
-            # the Megatron specs, caches via the paged/dense cache rules
-            # (paged pool global over data, kv heads over "model", block
-            # tables and slot batch over "data"), slot state over "data".
-            # The host-side allocator (self.manager) stays global — block
-            # ids are placement-free; only the device tables shard.  The
-            # jits below trace under activation_mesh and pin every
-            # returned cache/state leaf back to its placement, so
-            # donation keeps layouts stable step over step.
+            # the Megatron specs (a no-op for weights that arrive placed
+            # so, a device-to-device reshard otherwise: the model is never
+            # gathered onto one device), caches by the paged/dense cache
+            # rules as ``alloc`` made them (paged pool global over data,
+            # kv heads over "model", block tables and slot batch over
+            # "data"), slot state over "data".  The host-side allocator
+            # (self.manager) stays global — block ids are placement-free;
+            # only the device tables shard.  The jits below trace under
+            # activation_mesh (the Pallas kernels then run shard-local)
+            # and pin every returned cache/state leaf back to its
+            # placement, so donation keeps layouts stable step over step.
             fsdp = global_config.fsdp_params
             model = self.model = jax.device_put(
                 model, model_shardings(model, mesh, fsdp=fsdp))
             self._cache_sh = cache_shardings(self.cache, mesh)
-            self.cache = jax.device_put(self.cache, self._cache_sh)
             if self.draft_cache is not None:
                 draft_model = self.draft_model = jax.device_put(
                     draft_model, model_shardings(draft_model, mesh,
                                                  fsdp=fsdp))
                 self._draft_sh = cache_shardings(self.draft_cache, mesh)
-                self.draft_cache = jax.device_put(self.draft_cache,
-                                                  self._draft_sh)
             else:
                 self._draft_sh = None
             self._state_sh = _SlotArrays(*(data_sharding(mesh, a.shape)
@@ -932,14 +940,16 @@ class ContinuousEngine:
         if self.manager is None or not self._table_dirty:
             return
         with _span("serve.table_flush"):
+            # upload a copy: a transfer may read its host buffer after the
+            # call returns, and the manager edits its mirror in place
+            tables = self.manager.tables.copy()
             self.cache = self.cache._replace(
-                table=self._put_table(self.manager.tables,
-                                      draft=False))
+                table=self._put_table(tables, draft=False))
             if self.draft_cache is not None:
                 # materialized separately on purpose: the two caches must
                 # never share a device buffer (both are donated to jits)
                 self.draft_cache = self.draft_cache._replace(
-                    table=self._put_table(self.manager.tables, draft=True))
+                    table=self._put_table(tables, draft=True))
             self._table_dirty = False
 
     def _put_table(self, tables: np.ndarray, *, draft: bool) -> jax.Array:
@@ -949,7 +959,7 @@ class ContinuousEngine:
         if self.mesh is None:
             return jnp.asarray(tables)
         sh = (self._draft_sh if draft else self._cache_sh).table
-        return jax.device_put(np.asarray(tables), sh)
+        return jax.device_put(tables, sh)
 
     # -- cancellation --------------------------------------------------------
 
@@ -1445,12 +1455,26 @@ class ContinuousEngine:
         decode kernel walks: over the plain decode steps since
         :meth:`reset_stats`, the sum of each slot's walk bound
         (:func:`repro.kernels.paged_attention.walk_bound`) over the sum
-        of ``batch x max_table``; 0.0 before the first decode step."""
+        of ``batch x max_table``; 0.0 before the first decode step.
 
-        def _leaf_bytes(cache):
-            return sum(a.size * a.dtype.itemsize
+        ``model_shards`` is the size of the mesh's "model" axis (1 off a
+        mesh), and ``pool_bytes_per_device`` the KV state (draft mirror
+        included) that one device holds: ``kv_allocated_bytes`` over the
+        model shards where the kv heads divide them."""
+
+        def _leaf_bytes(cache, per_device=False):
+            return sum(math.prod(a.sharding.shard_shape(a.shape)
+                                 if per_device else a.shape)
+                       * a.dtype.itemsize
                        for f, a in zip(cache._fields, cache)
                        if f not in ("length", "table"))
+
+        shards = {"model_shards": (1 if self.mesh is None
+                                   else self.mesh.shape.get("model", 1)),
+                  "pool_bytes_per_device": sum(
+                      _leaf_bytes(c, per_device=True)
+                      for c in (self.cache, self.draft_cache)
+                      if c is not None)}
 
         if self.manager is None:
             leaves = {f: a for f, a in zip(self.cache._fields, self.cache)
@@ -1470,7 +1494,7 @@ class ContinuousEngine:
                 stats["kv_lane_tokens"] = k.shape[2]
                 if self.cache_kind in ("ring", "hybrid"):
                     stats["kv_ring_bytes"] = 2 * k.size * k.dtype.itemsize
-            return stats
+            return {**stats, **shards}
         alloc = 2 * self.cache.k.size * self.cache.k.dtype.itemsize
         block_bytes = 2 * (self.cache.k.size // self.n_blocks
                            ) * self.cache.k.dtype.itemsize
@@ -1495,7 +1519,7 @@ class ContinuousEngine:
             "decode_walk_share": (self._walk_entries / self._table_entries
                                   if self._table_entries else 0.0),
             "decode_kernel": self.decode_kernel,
-            "prefill_kernel": self.prefill_kernel})
+            "prefill_kernel": self.prefill_kernel, **shards})
         return stats
 
     def prefill_stats(self) -> dict:

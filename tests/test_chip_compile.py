@@ -16,14 +16,19 @@ collection would make the others collect different tests.
 
 import functools
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
+from repro.dist.sharding import activation_mesh, model_shardings
 from repro.kernels import chunk_attention, led_matmul, paged_attention
 from repro.launch.hlo_analysis import pallas_kernels
+from repro.nn.attention import Attention, PagedKVCache
 
 # qwen2.5-3b attention and MLP widths; the engine's block size and the
 # slots / context / chunk width the chip smoke serves with
@@ -35,16 +40,26 @@ N_BLOCKS = SLOTS * N_TABLE
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler installed here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The yi-9b cell's mesh: ``data`` 1 x ``model`` 4 over the host."""
+    return Mesh(np.asarray(topo.devices[:4]).reshape(1, 4), ("data", "model"))
 
 
 def _compile(fn, kernel, *specs, sharding):
@@ -87,3 +102,67 @@ def test_led_matmul_compiles(one_chip, rows, d_in, d_out):
     _compile(functools.partial(led_matmul, interpret=False), "led_matmul_2d",
              ((rows, d_in), jnp.bfloat16), ((d_in, RANK), jnp.bfloat16),
              ((RANK, d_out), jnp.bfloat16), sharding=one_chip)
+
+
+# yi-9b's attention at tensor parallelism 4: 32 query and 4 kv heads of 128
+# over hidden 4096, so each chip's kernels see 8 query heads and 1 kv head;
+# the chat cell's 32 slots to 4,096 tokens in blocks of 16
+YI_D, YI_HEADS, YI_KVH, YI_SLOTS, YI_TABLE = 4096, 32, 4, 32, 4096 // BLOCK
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """The attention layer's kernels compiled, not interpreted, while a
+    test lowers it here (the program picks interpret mode by platform)."""
+    for name in ("paged_attention", "chunk_attention"):
+        monkeypatch.setattr(sys.modules[f"repro.kernels.{name}"],
+                            "default_interpret", lambda: False)
+
+
+@pytest.mark.parametrize("role", ["decode", "chunk"])
+def test_shard_local_kernels_compile(four_chips, compiled_kernels, role):
+    """Both kernels, as the yi-9b cell runs them: under ``shard_map`` on a
+    1 x 4 mesh, each chip's kernel at its own 8 query heads and 1 kv head
+    of the pool (bf16, 32 slots, 256 table entries)."""
+    attn = jax.eval_shape(lambda k: Attention.create(
+        k, YI_D, YI_HEADS, YI_KVH, head_dim=HD, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
+
+    def placed(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            tree, shardings)
+
+    def spec(shape, dtype, *dims):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(
+            four_chips, PartitionSpec(*dims)))
+
+    attn = placed(attn, model_shardings(attn, four_chips))
+    pool = spec((YI_KVH, YI_SLOTS * YI_TABLE, BLOCK, HD), jnp.bfloat16,
+                "model")
+    table = spec((YI_SLOTS, YI_TABLE), jnp.int32)
+    length = spec((YI_SLOTS,), jnp.int32)
+    cache = PagedKVCache(pool, pool, table, length)
+    if role == "decode":
+        def step(attn, x, cache):
+            return attn.decode(x, cache, decode_kernel="pallas")
+
+        x = spec((YI_SLOTS, 1, YI_D), jnp.bfloat16)
+        args, kernel = (attn, x, cache), "_paged_attention_call"
+        local = f"bf16[{YI_SLOTS},1,8,{HD}]"
+    else:
+        def step(attn, x, cache, slot, dst):
+            return attn.prefill_chunk(x, cache, slot=slot, offset=slot,
+                                      n_valid=slot, dst=dst,
+                                      prefill_kernel="pallas")
+
+        x = spec((1, CHUNK, YI_D), jnp.bfloat16)
+        args = (attn, x, cache, spec((), jnp.int32), spec((CHUNK,),
+                                                           jnp.int32))
+        kernel, local = "_chunk_attention_call", f"bf16[1,{CHUNK * 8},{HD}]"
+    with activation_mesh(four_chips):
+        text = jax.jit(step).lower(*args).compile().as_text()
+    assert pallas_kernels(text) == {kernel}
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert calls and all(local in ln.split(" = ", 1)[1].split(" ", 1)[0]
+                         for ln in calls), calls

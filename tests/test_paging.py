@@ -176,6 +176,32 @@ def test_cache_full_frozen_slot_does_not_corrupt_neighbors(setup):
             f"frozen cache-full slot corrupted prompt_len={plen}"
 
 
+def test_table_upload_is_a_snapshot_of_the_mirror(setup):
+    """The device block table is what the host mirror held at the flush.
+    A host array handed to a transfer may be read after the call returns
+    (and on the CPU an aligned one is aliased outright), while the
+    manager edits its mirror in place at the next admission or release:
+    uploading the mirror itself let those edits reach a table a program
+    was already given."""
+    model, cfg = setup
+    eng = ContinuousEngine(model, cfg, batch=4, max_len=64,
+                           max_prompt_len=16, kv_layout="paged",
+                           block_size=4)
+    mirror = eng.manager.tables
+    raw = np.empty(mirror.nbytes + 64, np.uint8)
+    start = -raw.ctypes.data % 64  # an aligned mirror, as the CPU aliases
+    aligned = raw[start:start + mirror.nbytes].view(np.int32).reshape(
+        mirror.shape)
+    aligned[...] = mirror
+    eng.manager.tables = aligned
+    eng._table_dirty = True
+    eng._flush_table()
+    flushed = np.asarray(eng.cache.table).copy()
+    aligned[...] = 0  # the manager's in-place edits after the flush
+    np.testing.assert_array_equal(np.asarray(eng.cache.table), flushed)
+    assert (flushed == eng.manager.sentinel).all()
+
+
 # ---- cache-kind capability probe (serve / structured rejection) -------------
 
 

@@ -59,6 +59,35 @@ def test_runtime_config_env_seeding(monkeypatch):
     assert "host_platform_device_count" in HOST_DEVICES_RECIPE
 
 
+def test_single_device_kernels_run_unwrapped():
+    """Off a mesh the engine's decode and chunk programs call both Pallas
+    kernels directly: no ``shard_map`` and no collective."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serve import ContinuousEngine
+
+    cfg = get_config("yi-9b").reduced()
+    model = build_model(jax.random.PRNGKey(0), cfg)
+    eng = ContinuousEngine(model, cfg, batch=2, max_len=32,
+                           max_prompt_len=16, kv_layout="paged",
+                           block_size=8, chunk_size=8,
+                           decode_kernel="pallas", prefill_kernel="pallas")
+    assert eng.mesh is None
+    decode = jax.make_jaxpr(eng._decode)(eng._models, eng.cache, eng.state,
+                                         eng._next_key())
+    i32 = jnp.int32
+    chunk = jax.make_jaxpr(eng._chunk_mid)(
+        eng._models, jnp.zeros((1, 8), i32), eng.cache, i32(0), i32(0),
+        i32(8), jnp.zeros((8,), i32))
+    for text in (str(decode), str(chunk)):
+        assert "pallas_call" in text
+        for prim in ("shard_map", "psum", "all_gather", "ppermute"):
+            assert prim not in text, prim
+
+
 # -- the sharded differential matrix (subprocess, 8 host devices) ------------
 
 _PRELUDE = """
@@ -84,7 +113,6 @@ _PRELUDE = """
 
 MATRIX_SCRIPT = textwrap.dedent(_PRELUDE + """
     from jax.sharding import PartitionSpec as P
-    from repro.nn.attention import UnsupportedCacheError
 
     # single-device references, both layouts
     ref = {}
@@ -129,20 +157,28 @@ MATRIX_SCRIPT = textwrap.dedent(_PRELUDE + """
             assert eng.cache.length.sharding.spec == P(None, "data")
             assert eng.state.tok.sharding.spec == P("data")
 
-    # pallas kernels are single-shard: refuse with the structured error
-    mesh = make_serve_mesh("1,2")
-    for knob in ("decode_kernel", "prefill_kernel"):
-        try:
-            ContinuousEngine(model, cfg, kv_layout="paged", block_size=8,
-                             mesh=mesh, **{knob: "pallas"}, **DIMS)
-            raise SystemExit(f"pallas {knob} accepted under tp=2")
-        except UnsupportedCacheError as e:
-            assert e.roadmap_item and "Pallas" in e.roadmap_item
+    # the pallas kernels run shard-local on any mesh: each model shard
+    # holds its kv heads' slice of the pool and no more
+    for spec in ("1,2", "2,1"):
+        mesh = make_serve_mesh(spec)
+        eng = ContinuousEngine(model, cfg, kv_layout="paged", block_size=8,
+                               mesh=mesh, decode_kernel="pallas",
+                               prefill_kernel="pallas", **DIMS)
+        st = eng.kv_stats()
+        assert st["model_shards"] == mesh.shape["model"]
+        assert st["pool_bytes_per_device"] * mesh.shape["model"] \
+            == st["kv_allocated_bytes"], st
 
-    # ... but tp=1 meshes (pure data parallelism) may keep the kernels
-    ContinuousEngine(model, cfg, kv_layout="paged", block_size=8,
-                     mesh=make_serve_mesh("2,1"), decode_kernel="pallas",
-                     **DIMS)
+    # a model committed to a mesh brings it: no mesh argument, same engine
+    mesh = make_serve_mesh("2,2")
+    from repro.dist.sharding import model_shardings
+    placed = jax.device_put(model, model_shardings(model, mesh))
+    eng = ContinuousEngine(placed, cfg, kv_layout="paged", block_size=8,
+                           **DIMS)
+    assert eng.mesh.shape == mesh.shape
+    assert eng.cache.k.sharding.spec == P(None, "model", None, None, None)
+    assert eng.kv_stats()["model_shards"] == 2
+    assert ContinuousEngine(model, cfg, **DIMS).mesh is None
     print("SHARDED_MATRIX_OK")
 """)
 
@@ -194,12 +230,71 @@ CANCEL_SPEC_SCRIPT = textwrap.dedent(_PRELUDE + """
 """)
 
 
-def _run(script: str) -> str:
+# a llama-shaped model (yi-9b reduced: 8:1 GQA over 4 kv heads, an untied
+# head, no bias) served with both Pallas kernels, interpreted on the CPU,
+# under chat-like traffic replayed on a fixed step schedule: requests
+# share two system prompts of two blocks each (prefix hits, one same-step
+# provider the dependent must wait for, one prompt that hits whole),
+# prompts and answers end on page boundaries, ten requests take turns on
+# four slots, and two urgent arrivals preempt running decodes that later
+# resume as prefix hits.  Every mesh must emit the single-device tokens.
+PALLAS_SCRIPT = textwrap.dedent(_PRELUDE + """
+    import sys
+    from repro.serve import Request
+
+    cfg = get_config("yi-9b").reduced().replace(n_heads=32, n_kv_heads=4,
+                                                head_dim=8)
+    model = build_model(jax.random.PRNGKey(1), cfg)
+    assert model.lm_head is not None and cfg.n_heads // cfg.n_kv_heads == 8
+    BS = 8
+    rng = np.random.default_rng(7)
+    systems = [rng.integers(0, cfg.vocab, 2 * BS) for _ in range(2)]
+    # (step, system, user tokens, answer tokens, priority)
+    PLAN = [(0, 0, 8, 12, 2), (0, 0, 5, 11, 2), (0, 1, 0, 9, 2),
+            (0, 1, 3, 13, 2), (3, 0, 16, 6, 0), (5, 1, 7, 8, 1),
+            (6, 0, 0, 5, 2), (9, 1, 8, 8, 2), (12, 0, 1, 7, 2),
+            (14, 1, 15, 9, 2)]
+    PROMPTS = [np.concatenate([systems[s], rng.integers(0, cfg.vocab, u)])
+               .astype(np.int32) for _, s, u, _, _ in PLAN]
+
+    def serve(mesh):
+        eng = ContinuousEngine(model, cfg, batch=4, max_len=64,
+                               max_prompt_len=48, kv_layout="paged",
+                               block_size=BS, chunk_size=8,
+                               decode_kernel="pallas",
+                               prefill_kernel="pallas", mesh=mesh)
+        uids, done, step = [], [], 0
+        while step <= PLAN[-1][0] or not eng.scheduler.idle:
+            for i, (at, _, _, new, prio) in enumerate(PLAN):
+                if at == step:
+                    uids.append(eng.submit(Request(
+                        prompt=PROMPTS[i], max_new_tokens=new,
+                        priority=prio)))
+            done += eng.step()
+            step += 1
+            assert step < 500
+        got = {c.uid: tuple(c.tokens) for c in done}
+        return [got[u] for u in uids], eng.preempt_stats(), eng.kv_stats()
+
+    want, pre, _ = serve(None)
+    assert pre["preemptions"] >= 2 and pre["resumes"] >= 2, pre
+    assert [len(t) for t in want] == [p[3] for p in PLAN]
+    mesh = make_serve_mesh(sys.argv[1])
+    got, mpre, kv = serve(mesh)
+    assert got == want, (got, want)
+    assert mpre == pre, (mpre, pre)
+    assert kv["model_shards"] == mesh.shape["model"]
+    assert kv["prefix_hit_tokens"] > 0
+    print("SHARDED_PALLAS_OK")
+""")
+
+
+def _run(script: str, *args: str) -> str:
     env = dict(os.environ)
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = os.path.abspath(src)
-    r = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                       text=True, env=env, timeout=600)
+    r = subprocess.run([sys.executable, "-c", script, *args],
+                       capture_output=True, text=True, env=env, timeout=600)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]
     return r.stdout
 
@@ -212,3 +307,10 @@ def test_sharded_engine_matches_single_device_and_generate():
 @pytest.mark.slow
 def test_sharded_cancellation_and_spec_decode():
     assert "SHARDED_CANCEL_SPEC_OK" in _run(CANCEL_SPEC_SCRIPT)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("spec", ["1,4", "2,2"],
+                         ids=["model4", "data2-model2"])
+def test_sharded_pallas_kernels_match_single_device(spec):
+    assert "SHARDED_PALLAS_OK" in _run(PALLAS_SCRIPT, spec)

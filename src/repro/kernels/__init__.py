@@ -10,6 +10,9 @@
   with offset-relative causal masking (chunk_attention.py;
   ``chunk_attention_dense`` serves the dense per-slot lane through the
   same kernel body via an identity block table).
+* ``pool_write`` — the in-place write of a step's new K/V rows into one
+  layer of the stacked paged pool, block by block by DMA
+  (pool_write.py; its oracle is the XLA scatter it replaces).
 * ``ref`` — pure-jnp oracles for all of them; the correctness references
   the interpret-mode CI matrix pins the kernels against (see README.md).
 """

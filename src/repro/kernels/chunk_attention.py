@@ -2,9 +2,9 @@
 
 One prompt chunk of ``W`` bucket-padded tokens for ONE serving slot
 attends against (a) the slot's resident KV prefix — everything below
-``offset``, streamed block-by-block from the paged pool through the
-slot's block table — and (b) the chunk's own fresh K/V rows under an
-in-chunk causal + padding mask.  This is exactly the attention
+``offset``, streamed block-by-block from one layer of the paged pool
+through the slot's block table — and (b) the chunk's own fresh K/V rows
+under an in-chunk causal + padding mask.  This is exactly the attention
 :meth:`repro.nn.attention.Attention.prefill_chunk` computes for its
 valid rows, minus the dense gather: the reference path first
 materializes the whole ``(max_table * block_size, kv_heads, head_dim)``
@@ -40,12 +40,16 @@ division rather than NaN.
 
 GQA/MQA fall out of the layout: ``q`` is reshaped to ``(kv_heads,
 W * group, head_dim)`` (row ``r = w * group + g``) and each grid step
-attends one KV head's query block.  The pool is head-major, ``(kv_heads,
-n_blocks, block_size, head_dim)``, so every fetched block is ``(block_size,
-head_dim)`` in its last two dims, as the TPU compiler's tiling requires
-(see :mod:`repro.kernels.paged_attention`).  Under tensor parallelism it
-runs shard-local on one shard's KV heads and their query groups, like
-the decode kernel.
+attends one KV head's query block.  The pool is the stacked pool of every
+layer, head-major, ``(n_layers, kv_heads, n_blocks, block_size,
+head_dim)``, so every fetched block is ``(block_size, head_dim)`` in its
+last two dims, as the TPU compiler's tiling requires (see
+:mod:`repro.kernels.paged_attention`).  The layer to read rides in the
+scalar-prefetch ``meta`` operand beside the offset and the valid count,
+and the pool's block index map reads it, so the layer scan hands the
+kernel the whole pool and no layer slice is ever cut out of it.  Under
+tensor parallelism it runs shard-local on one shard's KV heads and their
+query groups, like the decode kernel.
 """
 
 from __future__ import annotations
@@ -101,8 +105,8 @@ def _chunk_attn_kernel(table_ref, meta_ref,  # scalar prefetch
     # at/past the offset hold nothing this chunk may attend — skip them.
     @pl.when((i < n_table) & (i * block_size < off))
     def _prefix():
-        k = kp_ref[0, 0].astype(jnp.float32)   # (block_size, head_dim)
-        v = vp_ref[0, 0].astype(jnp.float32)
+        k = kp_ref[0, 0, 0].astype(jnp.float32)  # (block_size, head_dim)
+        v = vp_ref[0, 0, 0].astype(jnp.float32)
         bid = table_ref[jnp.minimum(i, n_table - 1)]
         kpos = i * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_size), 1)
@@ -130,7 +134,7 @@ def _chunk_attn_kernel(table_ref, meta_ref,  # scalar prefetch
 def _chunk_attention_call(q3, k_pool, v_pool, k_chunk3, v_chunk3, table_row,
                           meta, *, interpret: bool):
     kvh, wg, hd = q3.shape
-    n_blocks, bs = k_pool.shape[1:3]
+    n_blocks, bs = k_pool.shape[2:4]
     w = k_chunk3.shape[1]
     n_table = table_row.shape[0]
 
@@ -140,15 +144,16 @@ def _chunk_attention_call(q3, k_pool, v_pool, k_chunk3, v_chunk3, table_row,
         # The final grid step (the chunk half) never reads the pool refs —
         # clamp its index into range for the prefetch DMA.
         safe_i = jnp.minimum(i, n_table - 1)
-        return (kh, jnp.minimum(table_ref[safe_i], n_blocks - 1), 0, 0)
+        return (meta_ref[2], kh, jnp.minimum(table_ref[safe_i], n_blocks - 1),
+                0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(kvh, n_table + 1),
         in_specs=[
             pl.BlockSpec((1, wg, hd), lambda kh, i, t, m: (kh, 0, 0)),
-            pl.BlockSpec((1, 1, bs, hd), kv_map),
-            pl.BlockSpec((1, 1, bs, hd), kv_map),
+            pl.BlockSpec((1, 1, 1, bs, hd), kv_map),
+            pl.BlockSpec((1, 1, 1, bs, hd), kv_map),
             pl.BlockSpec((1, w, hd), lambda kh, i, t, m: (kh, 0, 0)),
             pl.BlockSpec((1, w, hd), lambda kh, i, t, m: (kh, 0, 0)),
         ],
@@ -173,17 +178,19 @@ def _chunk_attention_call(q3, k_pool, v_pool, k_chunk3, v_chunk3, table_row,
 
 def chunk_attention(
     q: jax.Array,          # (W, heads, head_dim) — one slot's chunk queries
-    k_pool: jax.Array,     # (kv_heads, n_blocks, block_size, head_dim)
-    v_pool: jax.Array,     # (kv_heads, n_blocks, block_size, head_dim)
+    k_pool: jax.Array,     # ([layers,] kv_heads, n_blocks, block_size, hd)
+    v_pool: jax.Array,     # ([layers,] kv_heads, n_blocks, block_size, hd)
     table_row: jax.Array,  # (max_table,) int32 — ONE slot's block table
     k_chunk: jax.Array,    # (W, kv_heads, head_dim) — the chunk's fresh K
     v_chunk: jax.Array,    # (W, kv_heads, head_dim)
     offset: jax.Array,     # () int32 — absolute position of chunk row 0
     n_valid: jax.Array,    # () int32 — real (non-padding) rows in the chunk
+    layer: jax.Array | int = 0,  # () int32 — the pool layer to read
     *,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Fused prefill-chunk attention against the paged KV pool.
+    """Fused prefill-chunk attention against layer ``layer`` of the paged
+    KV pool (a pool without the layer dim is one layer).
 
     Returns ``(W, heads, head_dim)`` in ``q.dtype``.  Rows ``< n_valid``
     match :func:`repro.kernels.ref.chunk_attention_ref` and the dense
@@ -195,8 +202,10 @@ def chunk_attention(
     """
     if interpret is None:
         interpret = default_interpret()
+    if k_pool.ndim == 4:
+        k_pool, v_pool = k_pool[None], v_pool[None]
     w, heads, hd = q.shape
-    kvh, n_blocks, bs, hd_k = k_pool.shape
+    kvh, n_blocks, bs, hd_k = k_pool.shape[1:]
     if hd_k != hd or v_pool.shape != k_pool.shape:
         raise ValueError(
             f"pool/query shape mismatch: q {q.shape}, k {k_pool.shape}, "
@@ -218,7 +227,8 @@ def chunk_attention(
     kc3 = k_chunk.transpose(1, 0, 2)  # (kvh, W, hd)
     vc3 = v_chunk.transpose(1, 0, 2)
     meta = jnp.stack([jnp.asarray(offset, jnp.int32),
-                      jnp.asarray(n_valid, jnp.int32)])
+                      jnp.asarray(n_valid, jnp.int32),
+                      jnp.asarray(layer, jnp.int32)])
     out = _chunk_attention_call(q3, k_pool, v_pool, kc3, vc3,
                                 table_row.astype(jnp.int32), meta,
                                 interpret=interpret)

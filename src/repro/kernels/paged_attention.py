@@ -2,8 +2,11 @@
 
 Single-query (decode-step) attention straight against the paged KV layout
 of :class:`repro.nn.attention.PagedKVCache`: the shared head-major block
-pool ``(kv_heads, n_blocks, block_size, head_dim)``, the per-slot block
-tables, and the per-slot positions.  The dense-gather baseline first
+pool of every layer ``(n_layers, kv_heads, n_blocks, block_size,
+head_dim)`` with the layer to read, the per-slot block tables, and the
+per-slot positions.  The layer is a scalar-prefetch operand, so the
+layer scan hands the kernel the whole pool and no layer slice is ever
+cut out of it.  The dense-gather baseline first
 materializes a ``(batch, max_len, kv_heads, head_dim)`` view of every
 slot's cache (``pool[:, table[b, p // bs], p % bs]``) and then runs
 masked attention over it — a full HBM round-trip of the
@@ -27,7 +30,7 @@ the online-softmax carry, then a ``fori_loop`` runs over ``ceil(n_walk[b]
 the block size and table width alone; the last block may reach past the
 table, and its pages past the bound are neither fetched nor attended).
 The pool stays in HBM; each page below the bound is one
-``make_async_copy`` of block ``table[b, i]`` of KV head ``kh`` (clamped to
+``make_async_copy`` of block ``[layer, kh, table[b, i]]`` (clamped to
 a real row — the unmapped sentinel ``n_blocks`` is masked in-kernel
 instead) into one of two VMEM buffers, so block ``j + 1``'s copies are in
 flight while block ``j`` accumulates ``softmax(q k^T / sqrt(d)) v`` for
@@ -97,12 +100,12 @@ def walk_bound(table, pos, block_size: int, n_blocks: int):
     return xp.minimum(pos // block_size + 1, last_mapped)
 
 
-def _paged_attn_kernel(walk_ref, table_ref, pos_ref,  # scalar prefetch
+def _paged_attn_kernel(walk_ref, table_ref, pos_ref, layer_ref,  # prefetch
                        q_ref, k_hbm, v_hbm, o_ref,
                        k_buf, v_buf, sems, m_ref, l_ref, acc_ref, *,
                        block_size: int, n_blocks: int, n_table: int,
                        pages: int):
-    b, kh = pl.program_id(0), pl.program_id(1)
+    b, kh, layer = pl.program_id(0), pl.program_id(1), layer_ref[0]
     n_walk = walk_ref[b]
     pos = pos_ref[b]
     keys = pages * block_size
@@ -112,7 +115,8 @@ def _paged_attn_kernel(walk_ref, table_ref, pos_ref,  # scalar prefetch
         # a sentinel entry is clamped to a real block for the fetch; its
         # lanes are masked below
         bid = jnp.minimum(table_ref[b, j * pages + p], n_blocks - 1)
-        return [pltpu.make_async_copy(pool.at[kh, bid], buf.at[slot, p],
+        return [pltpu.make_async_copy(pool.at[layer, kh, bid],
+                                      buf.at[slot, p],
                                       sems.at[i, slot])
                 for i, (pool, buf) in enumerate(((k_hbm, k_buf),
                                                  (v_hbm, v_buf)))]
@@ -188,10 +192,10 @@ def _paged_attn_kernel(walk_ref, table_ref, pos_ref,  # scalar prefetch
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_attention_call(q4, k_pool, v_pool, table, pos, *,
+def _paged_attention_call(q4, k_pool, v_pool, table, pos, layer, *,
                           interpret: bool):
     batch, kvh, group, hd = q4.shape
-    n_blocks, bs = k_pool.shape[1:3]
+    n_blocks, bs = k_pool.shape[2:4]
     n_table = table.shape[1]
     pages = pages_per_block(bs, n_table)
     walk = walk_bound(table, pos, bs, n_blocks).astype(jnp.int32)
@@ -200,7 +204,7 @@ def _paged_attention_call(q4, k_pool, v_pool, table, pos, *,
         return (b, kh, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(batch, kvh),
         in_specs=[
             pl.BlockSpec((1, 1, group, hd), qo_map),
@@ -225,19 +229,21 @@ def _paged_attention_call(q4, k_pool, v_pool, table, pos, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(walk, table, pos, q4, k_pool, v_pool)
+    )(walk, table, pos, layer, q4, k_pool, v_pool)
 
 
 def paged_attention(
     q: jax.Array,       # (batch, heads, head_dim) — the one decode query
-    k_pool: jax.Array,  # (kv_heads, n_blocks, block_size, head_dim)
-    v_pool: jax.Array,  # (kv_heads, n_blocks, block_size, head_dim)
+    k_pool: jax.Array,  # ([layers,] kv_heads, n_blocks, block_size, head_dim)
+    v_pool: jax.Array,  # ([layers,] kv_heads, n_blocks, block_size, head_dim)
     table: jax.Array,   # (batch, max_table) int32; sentinel == n_blocks
     pos: jax.Array,     # (batch,) int32 — query position; attends kpos <= pos
+    layer: jax.Array | int = 0,  # () int32 — the pool layer to read
     *,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Fused single-query attention against the paged KV pool.
+    """Fused single-query attention against layer ``layer`` of the paged
+    KV pool (a pool without the layer dim is one layer).
 
     Returns ``(batch, heads, head_dim)`` in ``q.dtype``.  Semantics match
     :func:`repro.kernels.ref.paged_attention_ref` (same masking, fp32
@@ -247,8 +253,10 @@ def paged_attention(
     """
     if interpret is None:
         interpret = default_interpret()
+    if k_pool.ndim == 4:
+        k_pool, v_pool = k_pool[None], v_pool[None]
     batch, heads, hd = q.shape
-    kvh, n_blocks, bs, hd_k = k_pool.shape
+    kvh, n_blocks, bs, hd_k = k_pool.shape[1:]
     if hd_k != hd or v_pool.shape != k_pool.shape:
         raise ValueError(
             f"pool/query shape mismatch: q {q.shape}, k {k_pool.shape}, "
@@ -262,7 +270,9 @@ def paged_attention(
     q4 = q.reshape(batch, kvh, heads // kvh, hd)
     out = _paged_attention_call(q4, k_pool, v_pool,
                                 table.astype(jnp.int32),
-                                pos.astype(jnp.int32), interpret=interpret)
+                                pos.astype(jnp.int32),
+                                jnp.asarray(layer, jnp.int32).reshape(1),
+                                interpret=interpret)
     return out.reshape(batch, heads, hd)
 
 

@@ -62,9 +62,10 @@ class DenseBlock(Module):
         x = x + self.mlp(self.mlp_norm(x))
         return x, cache
 
-    def decode(self, x, cache: KVCache, decode_kernel: str = "reference"):
+    def decode(self, x, cache: KVCache, decode_kernel: str = "reference",
+               layer=0):
         a, cache = self.attn.decode(self.attn_norm(x), cache,
-                                    decode_kernel=decode_kernel)
+                                    decode_kernel=decode_kernel, layer=layer)
         x = x + a
         x = x + self.mlp(self.mlp_norm(x))
         return x, cache
@@ -109,9 +110,10 @@ class MoEBlock(Module):
         x = x + self.mlp(self.mlp_norm(x)).y
         return x, cache
 
-    def decode(self, x, cache: KVCache, decode_kernel: str = "reference"):
+    def decode(self, x, cache: KVCache, decode_kernel: str = "reference",
+               layer=0):
         a, cache = self.attn.decode(self.attn_norm(x), cache,
-                                    decode_kernel=decode_kernel)
+                                    decode_kernel=decode_kernel, layer=layer)
         x = x + a
         x = x + self.mlp(self.mlp_norm(x)).y
         return x, cache
@@ -205,10 +207,11 @@ class TransformerLM(Module):
 
         Pool k/v carry a leading layer dim (``(n_layers, kvh, n_blocks,
         block_size, hd)``, head-major per layer — see :class:`PagedKVCache`)
-        and per-layer lengths scan with the blocks;
-        the block table is layer-invariant (every layer mirrors the same
-        allocation) so it is stored once and closed over by the decode
-        scan.  Unmapped table entries hold the sentinel ``n_blocks``."""
+        and ride whole through the layer scans; per-layer lengths scan
+        with the blocks; the block table is layer-invariant (every layer
+        mirrors the same allocation) so it is stored once and closed over
+        by the scans.  Unmapped table entries hold the sentinel
+        ``n_blocks``."""
         if cfg.window:
             raise UnsupportedCacheError(
                 "paged KV cache requires global attention (cfg.window == 0)",
@@ -267,6 +270,28 @@ class TransformerLM(Module):
                                    cache.length.shape)
         return logits, new_cache._replace(length=new_len)
 
+    def _paged_scan(self, x, cache: PagedKVCache, step):
+        """Run ``step(block, x, layer_cache, layer)`` over the layers.
+
+        The pool k/v ride whole in the scan's carry, from the (donated)
+        argument to the result, and each layer gets the whole pool with
+        its index; only the blocks, the per-layer lengths and the layer
+        index are scanned over.  So no layer slice of the pool is cut out
+        and stacked back (a copy of the whole pool per call); the pool is
+        read and written in place at ``[layer]``."""
+        table = cache.table
+
+        def body(carry, xs):
+            x, k, v = carry
+            blk, ln, layer = xs
+            y, c2 = step(blk, x, PagedKVCache(k, v, table, ln), layer)
+            return (constrain_acts(y), c2.k, c2.v), c2.length
+
+        (x, k, v), ln = jax.lax.scan(
+            body, (x, cache.k, cache.v),
+            (self.blocks, cache.length, jnp.arange(self.n_layers)))
+        return x, PagedKVCache(k, v, table, ln)
+
     def prefill_chunk(self, tokens: jax.Array, cache, *, slot: jax.Array,
                       offset: jax.Array, n_valid: jax.Array,
                       dst: Optional[jax.Array] = None,
@@ -297,17 +322,9 @@ class TransformerLM(Module):
                   prefill_kernel=prefill_kernel)
 
         if isinstance(cache, PagedKVCache):
-            table = cache.table
-
-            def body(x, xs):
-                blk, (k, v, ln) = xs
-                y, c2 = blk.prefill_chunk(x, PagedKVCache(k, v, table, ln),
-                                          dst=dst, **kw)
-                return constrain_acts(y), (c2.k, c2.v, c2.length)
-
-            x, (k, v, ln) = jax.lax.scan(
-                body, x, (self.blocks, (cache.k, cache.v, cache.length)))
-            new_cache = PagedKVCache(k, v, table, ln)
+            x, new_cache = self._paged_scan(
+                x, cache, lambda blk, y, c, layer: blk.prefill_chunk(
+                    y, c, dst=dst, layer=layer, **kw))
         else:
             def body(x, xs):
                 blk, c = xs
@@ -330,27 +347,19 @@ class TransformerLM(Module):
         ``s``-step decode's — see :meth:`repro.nn.attention.Attention.
         decode`).
 
-        Accepts a dense :class:`KVCache` or a :class:`PagedKVCache`; for the
-        paged layout the block table is shared across layers, so only the
-        pool k/v and per-layer lengths ride through the layer scan, and
-        ``decode_kernel`` picks the paged attention implementation
-        (``"reference"`` dense gather vs ``"pallas"`` fused kernel — see
+        Accepts a dense :class:`KVCache` or a :class:`PagedKVCache` (see
+        :meth:`_paged_scan` for how the paged pool rides through the
+        layers); ``decode_kernel`` picks the paged implementation
+        (``"reference"`` XLA scatter + dense gather vs ``"pallas"``
+        in-place write + fused kernel — see
         :meth:`repro.nn.attention.Attention.decode`)."""
         x = constrain_acts(self.embed(token))
 
         if isinstance(cache, PagedKVCache):
-            table = cache.table
-
-            def body(x, xs):
-                blk, (k, v, ln) = xs
-                y, c2 = blk.decode(x, PagedKVCache(k, v, table, ln),
-                                   decode_kernel=decode_kernel)
-                return constrain_acts(y), (c2.k, c2.v, c2.length)
-
-            x, (k, v, ln) = jax.lax.scan(
-                body, x, (self.blocks, (cache.k, cache.v, cache.length)))
-            return self._head(self.final_norm(x)), PagedKVCache(k, v, table,
-                                                                ln)
+            x, new_cache = self._paged_scan(
+                x, cache, lambda blk, y, c, layer: blk.decode(
+                    y, c, decode_kernel=decode_kernel, layer=layer))
+            return self._head(self.final_norm(x)), new_cache
 
         def body(x, xs):
             blk, c = xs

@@ -32,7 +32,7 @@ from repro.nn.rotary import apply_rope
 NEG_INF = -1e30
 
 
-def _local(kernel, kv_heads: int, batch: int, arg_dims, out_dims: str):
+def _local(kernel, kv_heads: int, batch: int, arg_dims, out_dims):
     """``kernel`` as the traced step runs it.  Off a mesh, or on a mesh of
     one device, that is ``kernel`` itself, called as is.  On a mesh (the
     engine traces its steps inside ``activation_mesh``) it runs
@@ -44,8 +44,9 @@ def _local(kernel, kv_heads: int, batch: int, arg_dims, out_dims: str):
     groups of its KV heads: the kernel body sees an ordinary pool.
 
     ``arg_dims`` gives one string per argument and ``out_dims`` one for
-    the result, a letter per dim: ``h`` for heads, ``b`` for slots, ``.``
-    for a dim every shard holds whole."""
+    the result (a tuple of them for a tuple of results), a letter per
+    dim: ``h`` for heads, ``b`` for slots, ``.`` for a dim every shard
+    holds whole."""
     active = active_activation_mesh()
     if active is None or active[0].size == 1:
         return kernel
@@ -55,9 +56,44 @@ def _local(kernel, kv_heads: int, batch: int, arg_dims, out_dims: str):
     def spec(dims):
         return P(*(axes.get(c) for c in dims))
 
+    out_specs = (spec(out_dims) if isinstance(out_dims, str)
+                 else tuple(spec(d) for d in out_dims))
     return jax.shard_map(kernel, mesh=mesh,
                          in_specs=tuple(spec(d) for d in arg_dims),
-                         out_specs=spec(out_dims), check_vma=False)
+                         out_specs=out_specs, check_vma=False)
+
+
+def _write_rows(cache: "PagedKVCache", layer, start, blk, k, v, *,
+                kernel: str):
+    """The pools of ``cache`` with runs of new rows written into layer
+    ``layer``: run ``r`` holds rows at positions ``start[r] + j``, row
+    ``j`` going to block ``blk[r, j]`` (the sentinel ``n_blocks`` drops
+    it); k/v: ``(R, n, kv_heads, head_dim)``.
+
+    ``kernel="pallas"`` writes in place by DMA
+    (:func:`repro.kernels.pool_write.write_runs`), shard-local on a mesh
+    with every slot's rows on every data shard, since each holds the whole
+    pool.  ``"reference"`` is the XLA scatter the kernel is checked
+    against; on the TPU it re-tiles the whole pool."""
+    bs = cache.k.shape[3]
+    if kernel == "pallas":
+        from repro.kernels.pool_write import write_runs
+
+        heads = k.shape[2]
+        write = _local(write_runs, heads, 1,
+                       (".h...", ".h...", "", ".", "..", "..h.", "..h."),
+                       (".h...", ".h..."))
+        return write(cache.k, cache.v, layer, start, blk, k, v)
+    off = (start[:, None] + jnp.arange(blk.shape[1])) % bs
+
+    def scatter(pool, rows):
+        # (R, n, kvh, hd) -> (kvh, R, n, hd), the order pool[:, blk, off]
+        # indexes in
+        rows = jnp.moveaxis(rows, 2, 0).astype(pool.dtype)
+        return pool.at[layer].set(
+            pool[layer].at[:, blk, off].set(rows, mode="drop"))
+
+    return scatter(cache.k, k), scatter(cache.v, v)
 
 
 class UnsupportedCacheError(ValueError):
@@ -105,23 +141,31 @@ class PagedKVCache(NamedTuple):
     not to ``batch * max_len``.  Block ownership, refcounts, and prefix
     sharing live host-side in :mod:`repro.serve.paging`; table entries
     equal to ``n_blocks`` (one past the last block) are the unmapped
-    sentinel — scatters there drop, gathers clip into lanes the position
+    sentinel — writes there drop, gathers clip into lanes the position
     mask already excludes.
 
     The pool is head-major so that one KV head's block is a contiguous
     ``(block_size, head_dim)`` tile: the Pallas kernels fetch exactly
     that, and the TPU compiler refuses a block whose last two dims are
     not whole ``(8, 128)`` tiles.
+
+    The pool is the stacked pool of every layer, and it stays whole: the
+    model's layer scan carries it and hands each layer the whole pool
+    with the layer's index (``layer=`` of :meth:`Attention.decode` and
+    :meth:`Attention.prefill_chunk`), and only ``length`` is per layer.
+    The kernels read ``pool[layer]`` in place, and the Pallas row write
+    (:mod:`repro.kernels.pool_write`) writes it in place, so no step
+    program slices a layer out of the pool or stacks it back.
     """
 
-    k: jax.Array  # (kv_heads, n_blocks, block_size, head_dim)
-    v: jax.Array  # (kv_heads, n_blocks, block_size, head_dim)
+    k: jax.Array  # (n_layers, kv_heads, n_blocks, block_size, head_dim)
+    v: jax.Array  # (n_layers, kv_heads, n_blocks, block_size, head_dim)
     table: jax.Array  # (batch, max_blocks_per_seq) int32 pool block ids
-    length: jax.Array  # (batch,) int32 — valid positions per slot
+    length: jax.Array  # (n_layers, batch) int32 — valid positions per
+    # slot; (batch,) inside the layer scan, the one layer's
 
-    # constructed by ``TransformerLM.init_paged_cache`` (which stacks a
-    # leading n_layers dim onto k/v/length); no bare ``zeros`` here so the
-    # two shape contracts cannot drift apart
+    # constructed by ``TransformerLM.init_paged_cache``; no bare ``zeros``
+    # here so the two shape contracts cannot drift apart
 
 
 class Attention(Module):
@@ -313,7 +357,8 @@ class Attention(Module):
     def prefill_chunk(self, x: jax.Array, cache, *, slot: jax.Array,
                       offset: jax.Array, n_valid: jax.Array,
                       dst: Optional[jax.Array] = None,
-                      prefill_kernel: str = "reference"):
+                      prefill_kernel: str = "reference",
+                      layer: jax.Array | int = 0):
         """Consume one prompt chunk for ONE slot of a batched serving cache.
 
         ``x``: (1, W, dim) — a bucket-padded span of the slot's prompt whose
@@ -345,11 +390,13 @@ class Attention(Module):
         (``slot(p) = p % window``); older rows of an over-wide chunk and
         padding rows route to the out-of-range lane and drop.
 
-        :class:`PagedKVCache`: ``dst`` gives the flat pool row for each of
-        the W chunk positions — the engine points padding AND cached-prefix
-        positions at the out-of-range sentinel row, so ``mode='drop'``
-        leaves shared blocks untouched (a prefix hit is never rewritten,
-        even with identical bytes) — and attention gathers the slot's
+        :class:`PagedKVCache`: the cache holds every layer's pool and
+        this layer's lengths; ``layer`` says which layer of the pool is
+        this one's.  ``dst`` gives the flat pool row for each of the W
+        chunk positions — the engine points padding AND cached-prefix
+        positions at the out-of-range sentinel row, whose writes drop, so
+        shared blocks stay untouched (a prefix hit is never rewritten,
+        even with identical bytes) — and attention reads the slot's
         logical lane through its block table.
 
         ``prefill_kernel`` selects the chunk attention implementation for
@@ -357,10 +404,13 @@ class Attention(Module):
         masked softmax above; ``"pallas"`` is the flash-style
         :func:`repro.kernels.chunk_attention` kernel — prefix blocks
         stream through VMEM inside an online-softmax loop and the
-        gathered lane view is never materialized.  Valid rows match the
-        reference to float tolerance (padding rows carry no contract —
-        the engine never reads them); ring-buffer lanes refuse the
-        kernel (their wraparound gather has no paged-pool analogue).
+        gathered lane view is never materialized.  On the paged layout it
+        also picks how the rows are written: an XLA scatter, or the
+        in-place DMA write of :mod:`repro.kernels.pool_write`.  Valid
+        rows match the reference to float tolerance (padding rows carry
+        no contract — the engine never reads them); ring-buffer lanes
+        refuse the kernel (their wraparound gather has no paged-pool
+        analogue).
 
         Returns ``(chunk outputs (1, W, dim), updated cache)`` with the
         slot's length advanced to ``offset + n_valid``.
@@ -376,31 +426,29 @@ class Attention(Module):
                 raise NotImplementedError(
                     "paged chunked prefill supports global attention only; "
                     "sliding-window layers use the ring-buffer KVCache path")
-            bs = cache.k.shape[2]
+            bs = cache.k.shape[3]
             max_table = cache.table.shape[1]
-            # flat rows -> (block, row); the sentinel row n_blocks * bs
-            # lands on block n_blocks, out of range, and drops
-            blk, off = dst // bs, dst % bs
-            pool_k = cache.k.at[:, blk, off].set(
-                k[0].swapaxes(0, 1).astype(cache.k.dtype), mode="drop")
-            pool_v = cache.v.at[:, blk, off].set(
-                v[0].swapaxes(0, 1).astype(cache.v.dtype), mode="drop")
+            # flat rows -> blocks; the sentinel row n_blocks * bs lands on
+            # block n_blocks, out of range, and drops
+            pool_k, pool_v = _write_rows(
+                cache, layer, jnp.reshape(offset, (1,)), (dst // bs)[None],
+                k, v, kernel=prefill_kernel)
             if prefill_kernel == "pallas":
                 from repro.kernels.chunk_attention import chunk_attention
 
                 kernel = _local(chunk_attention, self.num_kv_heads, 1,
-                                (".h.", "h...", "h...", ".", ".h.", ".h.",
-                                 "", ""), ".h.")
+                                (".h.", ".h...", ".h...", ".", ".h.", ".h.",
+                                 "", "", ""), ".h.")
                 out = kernel(
                     q[0], pool_k, pool_v, cache.table[slot],
                     k[0].astype(pool_k.dtype), v[0].astype(pool_v.dtype),
-                    offset, n_valid).reshape(1, w, -1)
+                    offset, n_valid, layer).reshape(1, w, -1)
             else:
                 kpos = jnp.arange(max_table * bs)
                 blk_s, off_s = cache.table[slot, kpos // bs], kpos % bs
                 # (kvh, S, hd) -> (1, S, kvh, hd)
-                gk = pool_k[:, blk_s, off_s].swapaxes(0, 1)[None]
-                gv = pool_v[:, blk_s, off_s].swapaxes(0, 1)[None]
+                gk = pool_k[layer][:, blk_s, off_s].swapaxes(0, 1)[None]
+                gv = pool_v[layer][:, blk_s, off_s].swapaxes(0, 1)[None]
                 valid = kpos[None, :] <= qpos[:, None]  # (W, S)
                 out = self._attend(q, gk.astype(x.dtype), gv.astype(x.dtype),
                                    valid[None, None])
@@ -474,7 +522,8 @@ class Attention(Module):
         return constrain_acts(self.o_proj(out)), new_cache
 
     def decode(self, x: jax.Array, cache, *,
-               decode_kernel: str = "reference") -> tuple[jax.Array, "KVCache"]:
+               decode_kernel: str = "reference",
+               layer: jax.Array | int = 0) -> tuple[jax.Array, "KVCache"]:
         """Decode step for ``s`` new tokens per row. x: (batch, s, dim).
 
         ``s == 1`` is the ordinary autoregressive step.  ``s > 1`` is the
@@ -491,15 +540,18 @@ class Attention(Module):
         (lock-step batch: every row sits at the same position) or a
         ``(batch,)`` vector (per-slot mode for continuous batching: each row
         advances independently, with its own RoPE position, cache write
-        offset, and validity mask).  With a :class:`PagedKVCache`, K/V rows
-        are scattered to / gathered from the shared block pool through each
-        slot's block table; ``decode_kernel`` selects the paged attention
-        implementation (``"reference"`` = dense gather + masked softmax,
-        ``"pallas"`` = the fused block-streaming kernel, single-token steps
-        only — multi-token steps fall back to the reference gather) and is
-        ignored for dense caches."""
+        offset, and validity mask).  With a :class:`PagedKVCache` (every
+        layer's pool, this layer's lengths, ``layer`` the index of this
+        layer in the pool), K/V rows are written to / read from the
+        shared block pool through each slot's block table;
+        ``decode_kernel`` selects the paged implementation
+        (``"reference"`` = XLA scatter, dense gather + masked softmax;
+        ``"pallas"`` = in-place DMA write and the fused block-streaming
+        kernel, single-token steps only — multi-token steps read through
+        the reference gather) and is ignored for dense caches."""
         if isinstance(cache, PagedKVCache):
-            return self._decode_paged(x, cache, kernel=decode_kernel)
+            return self._decode_paged(x, cache, kernel=decode_kernel,
+                                      layer=layer)
         b, s, _ = x.shape
         pos = cache.length
         per_slot = pos.ndim == 1
@@ -568,28 +620,31 @@ class Attention(Module):
         return constrain_acts(self.o_proj(out)), KVCache(new_k, new_v, pos + s)
 
     def _decode_paged(self, x: jax.Array, cache: PagedKVCache,
-                      kernel: str = "reference"
+                      kernel: str = "reference", layer: jax.Array | int = 0
                       ) -> tuple[jax.Array, PagedKVCache]:
-        """Decode ``s`` tokens per slot against the shared block pool.
+        """Decode ``s`` tokens per slot against layer ``layer`` of the
+        shared block pool.
 
-        Each new K/V row is scattered to block ``table[b, p // bs]``, row
-        ``p % bs`` for ``p = pos .. pos + s - 1`` (``mode='drop'``: slots
-        whose table entry is the unmapped sentinel — finished, never
-        admitted, or positions past the slot's block reservation — write
-        nowhere, so a frozen slot can never clobber a block recycled to
-        another request).  ``kernel="reference"`` (the dense-gather
-        baseline) then gathers every mapped pool row back into logical
+        Each new K/V row goes to block ``table[b, p // bs]``, row ``p %
+        bs`` for ``p = pos .. pos + s - 1``; slots whose table entry is
+        the unmapped sentinel — finished, never admitted, or positions
+        past the slot's block reservation — write nowhere, so a frozen
+        slot can never clobber a block recycled to another request.
+        ``kernel="reference"`` (the dense-gather baseline) writes with an
+        XLA scatter, then gathers every mapped pool row back into logical
         order and masks ``kpos > qpos`` per query; gathers through
         sentinel entries clip into masked lanes, and exactly-NEG_INF
         masking makes their contribution a hard zero, keeping outputs
         bit-identical to the dense per-slot layout.  ``kernel="pallas"``
+        writes in place by DMA (:mod:`repro.kernels.pool_write`) and
         replaces the gather + attention with the fused
         :func:`repro.kernels.paged_attention` kernel — blocks stream
         through VMEM inside a flash-style online-softmax loop and the
         dense ``(batch, max_len, kvh, hd)`` view is never materialized
         (sentinel and ``kpos > pos`` masking move in-kernel).  The kernel
         is single-query; multi-token steps (``s > 1``, the speculative
-        verify pass) fall back to the reference gather."""
+        verify pass) write by DMA and read through the reference
+        gather."""
         if self.window > 0:
             raise NotImplementedError(
                 "paged decode supports global attention only; sliding-window "
@@ -601,7 +656,7 @@ class Attention(Module):
         qpos = pos[:, None] + jnp.arange(s)[None, :]  # (b, s)
         positions = qpos.astype(jnp.int32)
         q, k, v = self._qkv(x, positions=positions, kv_positions=positions)
-        nb, bs = cache.k.shape[1:3]
+        nb, bs = cache.k.shape[2:4]
         max_table = cache.table.shape[1]
         # a slot frozen at pos == max_table*bs (cache_full eviction) would
         # index one past the table; clamp the lookup and route its write to
@@ -610,26 +665,23 @@ class Attention(Module):
         blk = jnp.take_along_axis(
             cache.table, jnp.minimum(qpos // bs, max_table - 1), axis=1)
         blk = jnp.where(qpos < max_table * bs, blk, nb)  # (b, s)
-        # (b, s, kvh, hd) -> (kvh, b, s, hd), the order pool[:, blk, off]
-        # indexes in
-        new_k = cache.k.at[:, blk, qpos % bs].set(
-            jnp.moveaxis(k, 2, 0).astype(cache.k.dtype), mode="drop")
-        new_v = cache.v.at[:, blk, qpos % bs].set(
-            jnp.moveaxis(v, 2, 0).astype(cache.v.dtype), mode="drop")
+        new_k, new_v = _write_rows(cache, layer, pos, blk, k, v,
+                                   kernel=kernel)
         if kernel == "pallas" and s == 1:
             from repro.kernels.paged_attention import paged_attention
 
             kernel = _local(paged_attention, self.num_kv_heads, b,
-                            ("bh.", "h...", "h...", "b.", "b"), "bh.")
-            out = kernel(q[:, 0], new_k, new_v, cache.table, pos)
+                            ("bh.", ".h...", ".h...", "b.", "b", ""), "bh.")
+            out = kernel(q[:, 0], new_k, new_v, cache.table, pos, layer)
             out = out.reshape(b, 1, self.num_heads * self.head_dim)
         else:
             kpos = jnp.arange(max_table * bs)
             blk_s, off_s = cache.table[:, kpos // bs], (kpos % bs)[None, :]
             # (kvh, b, S, hd) -> (b, S, kvh, hd)
-            gk = jnp.moveaxis(new_k[:, blk_s, off_s], 0, 2).astype(x.dtype)
-            gv = jnp.moveaxis(new_v[:, blk_s, off_s], 0, 2).astype(x.dtype)
+            gk = jnp.moveaxis(new_k[layer][:, blk_s, off_s], 0, 2)
+            gv = jnp.moveaxis(new_v[layer][:, blk_s, off_s], 0, 2)
             valid = kpos[None, None, :] <= qpos[:, :, None]  # (b, s, S)
-            out = self._attend(q, gk, gv, valid[:, None])
+            out = self._attend(q, gk.astype(x.dtype), gv.astype(x.dtype),
+                               valid[:, None])
         return (constrain_acts(self.o_proj(out)),
                 PagedKVCache(new_k, new_v, cache.table, pos + s))

@@ -6,17 +6,22 @@ does not fit the ``(8, 128)`` tiling or a kernel that wants more VMEM
 than it may use.  These tests compile each kernel at qwen2.5-3b serving
 shapes for a ``v5e:2x2`` topology that is described, not attached
 (``jax.experimental.topologies``), so such a refusal fails here and not
-on the chip.  Nothing runs: a compile that passes says nothing about
-results or times.
+on the chip.  They also compile the model's decode and chunk step
+programs whole and read the compiled program for copies of the KV pool,
+which only the TPU compiler's layout choices show.  Nothing runs: a
+compile that passes says nothing about results or times.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and a test worker that describes it at
 collection would make the others collect different tests.
 """
 
+import contextlib
 import functools
+import importlib
+import math
 import os
-import sys
+import re
 
 import jax
 import jax.numpy as jnp
@@ -114,16 +119,17 @@ YI_D, YI_HEADS, YI_KVH, YI_SLOTS, YI_TABLE = 4096, 32, 4, 32, 4096 // BLOCK
 def compiled_kernels(monkeypatch):
     """The attention layer's kernels compiled, not interpreted, while a
     test lowers it here (the program picks interpret mode by platform)."""
-    for name in ("paged_attention", "chunk_attention"):
-        monkeypatch.setattr(sys.modules[f"repro.kernels.{name}"],
+    for name in ("paged_attention", "chunk_attention", "pool_write"):
+        monkeypatch.setattr(importlib.import_module(f"repro.kernels.{name}"),
                             "default_interpret", lambda: False)
 
 
 @pytest.mark.parametrize("role", ["decode", "chunk"])
 def test_shard_local_kernels_compile(four_chips, compiled_kernels, role):
-    """Both kernels, as the yi-9b cell runs them: under ``shard_map`` on a
-    1 x 4 mesh, each chip's kernel at its own 8 query heads and 1 kv head
-    of the pool (bf16, 32 slots, 256 table entries)."""
+    """The kernels, as the yi-9b cell runs them: under ``shard_map`` on a
+    1 x 4 mesh, each chip's kernels at its own 8 query heads and 1 kv
+    head of the whole stacked pool (bf16, 32 slots, 256 table entries,
+    layer 1 of 2): the attention kernel and the in-place row write."""
     attn = jax.eval_shape(lambda k: Attention.create(
         k, YI_D, YI_HEADS, YI_KVH, head_dim=HD, dtype=jnp.bfloat16),
         jax.random.PRNGKey(0))
@@ -138,31 +144,135 @@ def test_shard_local_kernels_compile(four_chips, compiled_kernels, role):
             four_chips, PartitionSpec(*dims)))
 
     attn = placed(attn, model_shardings(attn, four_chips))
-    pool = spec((YI_KVH, YI_SLOTS * YI_TABLE, BLOCK, HD), jnp.bfloat16,
+    layers, n_blocks = 2, YI_SLOTS * YI_TABLE
+    pool = spec((layers, YI_KVH, n_blocks, BLOCK, HD), jnp.bfloat16, None,
                 "model")
     table = spec((YI_SLOTS, YI_TABLE), jnp.int32)
     length = spec((YI_SLOTS,), jnp.int32)
     cache = PagedKVCache(pool, pool, table, length)
+    layer = spec((), jnp.int32)
     if role == "decode":
-        def step(attn, x, cache):
-            return attn.decode(x, cache, decode_kernel="pallas")
+        def step(attn, x, cache, layer):
+            return attn.decode(x, cache, decode_kernel="pallas", layer=layer)
 
         x = spec((YI_SLOTS, 1, YI_D), jnp.bfloat16)
-        args, kernel = (attn, x, cache), "_paged_attention_call"
+        args, kernel = (attn, x, cache, layer), "_paged_attention_call"
         local = f"bf16[{YI_SLOTS},1,8,{HD}]"
     else:
-        def step(attn, x, cache, slot, dst):
+        def step(attn, x, cache, layer, slot, dst):
             return attn.prefill_chunk(x, cache, slot=slot, offset=slot,
                                       n_valid=slot, dst=dst,
-                                      prefill_kernel="pallas")
+                                      prefill_kernel="pallas", layer=layer)
 
         x = spec((1, CHUNK, YI_D), jnp.bfloat16)
-        args = (attn, x, cache, spec((), jnp.int32), spec((CHUNK,),
-                                                           jnp.int32))
+        args = (attn, x, cache, layer, spec((), jnp.int32),
+                spec((CHUNK,), jnp.int32))
         kernel, local = "_chunk_attention_call", f"bf16[1,{CHUNK * 8},{HD}]"
     with activation_mesh(four_chips):
         text = jax.jit(step).lower(*args).compile().as_text()
-    assert pallas_kernels(text) == {kernel}
-    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    assert calls and all(local in ln.split(" = ", 1)[1].split(" ", 1)[0]
-                         for ln in calls), calls
+    assert pallas_kernels(text) == {kernel, "_pool_write_call"}
+    local_pool = f"bf16[{layers},1,{n_blocks},{BLOCK},{HD}]"
+    calls = [ln.split(" = ", 1)[1] for ln in text.splitlines()
+             if "tpu_custom_call" in ln]
+    assert calls and all(
+        (local_pool if "_pool_write_call" in c else local) in c.split(" ")[0]
+        for c in calls), calls
+
+
+# -- the step programs keep the pool in place ---------------------------------
+
+#: ops that move no data: a pool-sized result of one of these is the pool
+#: buffer itself, handed along
+_NO_COPY = {"parameter", "get-tuple-element", "bitcast", "tuple",
+            "custom-call"}
+_INSTR = re.compile(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def _pool_sized(text: str, pool_elems: int, n_layers: int) -> list:
+    """Instructions outside the kernels whose result holds as many
+    elements as the pool or as one layer of it (a copy, slice, re-tiling
+    or update of it, in whatever shape)."""
+    sizes = {pool_elems, pool_elems // n_layers}
+    out = []
+    for ln in text.splitlines():
+        m = _INSTR.match(ln)
+        if m and m.group(3) not in _NO_COPY and math.prod(
+                int(d) for d in m.group(2).split(",") if d) in sizes:
+            out.append(ln.strip()[:160])
+    return out
+
+
+@pytest.mark.parametrize("role", ["decode", "chunk"])
+@pytest.mark.parametrize("where", ["one_chip", "mesh_1x4"])
+def test_step_program_keeps_pool_in_place(topo, four_chips,
+                                          compiled_kernels, where, role):
+    """``TransformerLM.decode`` and ``prefill_chunk`` with the Pallas
+    kernels and the pool donated, as the engine's step programs run them:
+    at qwen2.5-3b widths on one chip and at yi-9b's on the 1 x 4 mesh (4
+    layers, 32 slots, 8,192 blocks of 16, bf16).  The pool rides whole
+    from the argument to the result: no instruction outside the kernels
+    has a pool- or layer-sized result, and the program's temporaries are
+    under 1/16 of the pool (a layer scan that slices the pool out and
+    stacks it back needs several pools of them)."""
+    from repro.configs import get_config
+    from repro.dist.sharding import cache_shardings
+    from repro.models import build_model
+
+    layers, slots, n_blocks = 4, 32, 8192
+    if where == "one_chip":
+        cfg, mesh = get_config("qwen2.5-3b").replace(n_layers=layers), None
+        sharding = SingleDeviceSharding(topo.devices[0])
+    else:
+        cfg, mesh = get_config("yi-9b").replace(n_layers=layers), four_chips
+        sharding = NamedSharding(mesh, PartitionSpec())
+    model = jax.eval_shape(lambda k: build_model(k, cfg),
+                           jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_paged_cache(
+        slots, n_blocks // slots * BLOCK, cfg, n_blocks=n_blocks,
+        block_size=BLOCK, dtype=jnp.bfloat16))
+    if mesh is None:
+        model_sh = jax.tree_util.tree_map(lambda _: sharding, model)
+        cache_sh = jax.tree_util.tree_map(lambda _: sharding, cache)
+    else:
+        model_sh = model_shardings(model, mesh)
+        cache_sh = cache_shardings(cache, mesh)
+
+    def placed(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            tree, shardings)
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+    def in_mesh():
+        return (activation_mesh(mesh) if mesh is not None
+                else contextlib.nullcontext())
+
+    if role == "decode":
+        def step(model, tok, cache):
+            with in_mesh():
+                return model.decode(tok, cache, decode_kernel="pallas")
+
+        rest = (spec((slots, 1)),)
+    else:
+        def step(model, toks, cache, slot, offset, n_valid, dst):
+            with in_mesh():
+                return model.prefill_chunk(
+                    toks, cache, slot=slot, offset=offset, n_valid=n_valid,
+                    dst=dst, prefill_kernel="pallas")
+
+        rest = (spec((1, CHUNK)),)
+    args = (placed(model, model_sh), *rest, placed(cache, cache_sh))
+    if role == "chunk":
+        args += (spec(()), spec(()), spec(()), spec((CHUNK,)))
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(*args).compile()
+    text = compiled.as_text()
+    kernel = ("_paged_attention_call" if role == "decode"
+              else "_chunk_attention_call")
+    assert pallas_kernels(text) == {kernel, "_pool_write_call"}
+    pool_elems = cache.k.size // (1 if mesh is None else mesh.size)
+    assert _pool_sized(text, pool_elems, layers) == []
+    pool_bytes = pool_elems * cache.k.dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes / 16, (temp, pool_bytes)

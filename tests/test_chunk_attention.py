@@ -211,6 +211,7 @@ def test_parity_random_cases(seed):
 # ---- layer-level three-way: Attention.prefill_chunk --------------------------
 
 DIM, HEADS, KVH, HD = 32, 4, 2, 8
+LAYERS, LAYER = 3, 1  # the layer tests write layer 1 of a 3-layer pool
 
 
 def _layer():
@@ -218,10 +219,12 @@ def _layer():
                             head_dim=HD, dtype=jnp.float32)
 
 
-def _paged_cache(batch, n_blocks, bs, n_table):
+def _paged_cache(batch, n_blocks, bs, n_table, n_layers=LAYERS):
+    """Every layer's pool, as the layer scan hands it to each layer, with
+    one layer's lengths."""
     return PagedKVCache(
-        k=jnp.zeros((KVH, n_blocks, bs, HD)),
-        v=jnp.zeros((KVH, n_blocks, bs, HD)),
+        k=jnp.zeros((n_layers, KVH, n_blocks, bs, HD)),
+        v=jnp.zeros((n_layers, KVH, n_blocks, bs, HD)),
         table=jnp.full((batch, n_table), n_blocks, jnp.int32),
         length=jnp.zeros((batch,), jnp.int32))
 
@@ -238,7 +241,7 @@ def _dst(table_row, off, w, n_valid, bs, n_blocks):
 def _scan_paged(attn, cache, x, slot, chunk, kernel):
     """Feed x (1, plen, dim) through paged prefill_chunk in chunk-sized
     spans (engine-style: blocks allocated up front here)."""
-    plen, bs = x.shape[1], cache.k.shape[2]
+    plen, bs = x.shape[1], cache.k.shape[3]
     outs = []
     for off in range(0, plen, chunk):
         n = min(chunk, plen - off)
@@ -250,8 +253,8 @@ def _scan_paged(attn, cache, x, slot, chunk, kernel):
             span, cache, slot=jnp.int32(slot), offset=jnp.int32(off),
             n_valid=jnp.int32(n),
             dst=_dst(cache.table[slot], off, chunk, n, bs,
-                     cache.k.shape[1]),
-            prefill_kernel=kernel)
+                     cache.k.shape[2]),
+            prefill_kernel=kernel, layer=jnp.int32(LAYER))
         outs.append(out[:, :n])
     return jnp.concatenate(outs, axis=1), cache
 
@@ -259,8 +262,8 @@ def _scan_paged(attn, cache, x, slot, chunk, kernel):
 def test_layer_paged_pallas_matches_reference_multichunk():
     """Three-way at the layer: the pallas path of Attention.prefill_chunk
     equals its own reference gather on every valid row across a chunked
-    scan, and the written K/V pool is bit-identical (writes are
-    kernel-independent by construction)."""
+    scan, and the written K/V pool is bit-identical (the in-place DMA
+    write against the XLA scatter), in the one layer it names."""
     attn = _layer()
     x = jax.random.normal(jax.random.PRNGKey(3), (1, 11, DIM))
     bs, n_table = 4, 4
@@ -278,8 +281,12 @@ def test_layer_paged_pallas_matches_reference_multichunk():
                                atol=1e-5, rtol=1e-5)
     np.testing.assert_array_equal(np.asarray(cache_p.k),
                                   np.asarray(cache_r.k))
+    np.testing.assert_array_equal(np.asarray(cache_p.v),
+                                  np.asarray(cache_r.v))
     np.testing.assert_array_equal(np.asarray(cache_p.length),
                                   np.asarray(cache_r.length))
+    others = np.delete(np.asarray(cache_p.k), LAYER, axis=0)
+    assert not others.any() and np.asarray(cache_p.k)[LAYER].any()
 
 
 def test_layer_dense_pallas_matches_reference():
